@@ -7,7 +7,9 @@ HBM->VMEM once with online softmax (the 'PIM internal bandwidth' analogue)
 and masks beyond each row's current length.
 
 Grid: (B, KH, n_kv); kv innermost, per-(b,kh) accumulator scratch carries
-partial (o, m, l) across cache blocks.
+partial (o, m, l) across cache blocks. The per-row lengths arrive by scalar
+prefetch (SMEM): a rank-1 VMEM block of one length would break the TPU's
+128-lane tiling rule.
 """
 from __future__ import annotations
 
@@ -32,7 +34,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    cur_len = len_ref[0]
+    cur_len = len_ref[pl.program_id(0)]
     # skip cache blocks entirely past the valid prefix
     @pl.when(ki * block_kv < cur_len)
     def _body():
@@ -75,22 +77,26 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     qg = q.reshape(B, KH, G, D)
     kern = functools.partial(_kernel, scale=scale, block_kv=bkv, n_kv=n_kv)
-    out = pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KH, n_kv),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ki, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bkv, D), lambda b, h, ki, lens: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, bkv, D), lambda b, h, ki, lens: (b, h, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, ki, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, D), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
             pltpu.VMEM((G,), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KH, G, D), q.dtype),
         interpret=interpret,
-    )(lengths, qg, k, v)
+    )(jnp.asarray(lengths, jnp.int32), qg, k, v)
     return out.reshape(B, H, D)

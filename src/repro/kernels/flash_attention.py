@@ -101,10 +101,10 @@ def _kernel_segmented(q_ref, k_ref, v_ref, qpos_ref, qseg_ref, kpos_ref,
     v = v_ref[0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    q_pos = qpos_ref[0][:, None]                            # (bq, 1)
-    q_seg = qseg_ref[0][:, None]
-    kv_pos = kpos_ref[0][None, :]                           # (1, bkv)
-    kv_seg = kseg_ref[0][None, :]
+    q_pos = qpos_ref[0]                                     # (bq, 1)
+    q_seg = qseg_ref[0]
+    kv_pos = kpos_ref[0]                                    # (1, bkv)
+    kv_seg = kseg_ref[0]
     mask = (q_seg == kv_seg) & (q_pos >= kv_pos)
     s = jnp.where(mask, s, NEG_INF)
     m_prev = m_ref[...]
@@ -167,13 +167,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if segment_info is not None:
         q_pos, q_seg, kv_pos, kv_seg = segment_info
         # rows broadcast over kv heads: (B, S) -> (B*KH, S), matching the
-        # (B, KH, ...) -> (B*KH, ...) flattening order of q/k/v
+        # (B, KH, ...) -> (B*KH, ...) flattening order of q/k/v. Query
+        # metadata is laid out as a column (B*KH, S, 1) and KV metadata as a
+        # row (B*KH, 1, Skv): the blocks' last two dims then satisfy the
+        # TPU's (8, 128) tiling rule and broadcast straight into the
+        # (bq, bkv) mask without a relayout.
         def rows(a, n):
             a = jnp.asarray(a, jnp.int32)
             assert a.shape == (B, n), (a.shape, (B, n))
             return jnp.repeat(a, KH, axis=0)
-        qpos_spec = pl.BlockSpec((1, bq), lambda b, g, qi, ki: (b, qi))
-        kpos_spec = pl.BlockSpec((1, bkv), lambda b, g, qi, ki: (b, ki))
+        def col(a, n):
+            return rows(a, n)[:, :, None]
+        def row(a, n):
+            return rows(a, n)[:, None, :]
+        qpos_spec = pl.BlockSpec((1, bq, 1), lambda b, g, qi, ki: (b, qi, 0))
+        kpos_spec = pl.BlockSpec((1, 1, bkv), lambda b, g, qi, ki: (b, 0, ki))
         kern = functools.partial(_kernel_segmented, scale=scale, n_kv=n_kv)
         out = pl.pallas_call(
             kern,
@@ -184,8 +192,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             out_shape=jax.ShapeDtypeStruct((B * KH, G, S, D), q.dtype),
             scratch_shapes=scratch,
             interpret=interpret,
-        )(qg, kf, vf, rows(q_pos, S), rows(q_seg, S),
-          rows(kv_pos, Skv), rows(kv_seg, Skv))
+        )(qg, kf, vf, col(q_pos, S), col(q_seg, S),
+          row(kv_pos, Skv), row(kv_seg, Skv))
         return out.reshape(B, H, S, D)
 
     kern = functools.partial(_kernel, scale=scale, causal=causal,

@@ -8,6 +8,9 @@ one write of y, state never leaves VMEM (the Mamba-official-kernel
 structure, adapted to TPU VMEM tiling).
 
 Grid: (B, d_tiles, n_chunks); chunks innermost carry the state scratch.
+The default (d_tile, chunk) = (128, 32) keeps the double-buffered a/u
+blocks inside v5e's 16 MiB scoped VMEM: the d_state lanes pad to 128, so a
+(256, 64) tiling asks for 32 MiB and the TPU compiler refuses it.
 """
 from __future__ import annotations
 
@@ -28,16 +31,12 @@ def _kernel(a_ref, u_ref, c_ref, y_ref, hout_ref, h_ref, *, chunk: int,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     def step(t, h):
-        ix = (slice(None), pl.dslice(t, 1))
-        a_t = pl.load(a_ref, ix + (slice(None), slice(None))
-                      )[0, 0].astype(jnp.float32)       # (dt, n)
-        u_t = pl.load(u_ref, ix + (slice(None), slice(None))
-                      )[0, 0].astype(jnp.float32)
-        c_t = pl.load(c_ref, ix + (slice(None),))[0, 0].astype(jnp.float32)
+        a_t = a_ref[:, pl.ds(t, 1)][0, 0].astype(jnp.float32)    # (dt, n)
+        u_t = u_ref[:, pl.ds(t, 1)][0, 0].astype(jnp.float32)
+        c_t = c_ref[:, pl.ds(t, 1)][0, 0].astype(jnp.float32)    # (n,)
         h = a_t * h + u_t
-        y_t = jnp.sum(h * c_t[None, :], axis=-1)        # (dt,)
-        pl.store(y_ref, ix + (slice(None),),
-                 y_t[None, None, :].astype(y_ref.dtype))
+        y_t = jnp.sum(h * c_t[None, :], axis=-1)                 # (dt,)
+        y_ref[:, pl.ds(t, 1)] = y_t[None, None, :].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -49,7 +48,7 @@ def _kernel(a_ref, u_ref, c_ref, y_ref, hout_ref, h_ref, *, chunk: int,
 
 
 def mamba_chunk(a: jax.Array, u: jax.Array, C: jax.Array, *,
-                d_tile: int = 256, chunk: int = 64,
+                d_tile: int = 128, chunk: int = 32,
                 interpret: bool = False):
     """a, u: (B, T, d, n); C: (B, T, n). Returns (y (B, T, d), h_T (B, d, n)).
     h_0 = 0 (prefill semantics)."""

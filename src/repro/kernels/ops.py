@@ -1,9 +1,10 @@
 """ops — jit'd dispatch layer over the Pallas kernels.
 
 Selects between the compiled TPU kernel, interpret-mode execution (CPU
-correctness), and the pure-XLA oracle path. Models call these; the PAS
-policy's phase-aware routing (core/pas.py ``route_fc_tpu``) decides when the
-GEMV kernel path replaces the GEMM path in serving.
+correctness), and the pure-XLA oracle path; off the chip the caller must
+choose. Models call these; the PAS policy's phase-aware routing
+(core/pas.py ``route_fc_tpu``) decides when the GEMV kernel path replaces
+the GEMM path in serving.
 """
 from __future__ import annotations
 
@@ -27,10 +28,16 @@ def on_tpu() -> bool:
 
 
 def _mode(impl: Optional[str]) -> str:
-    """impl: None (auto) | 'pallas' | 'interpret' | 'xla'."""
+    """impl: None (auto) | 'pallas' | 'interpret' | 'xla'. Auto means the
+    compiled kernel, which needs a TPU: off the chip it raises rather than
+    quietly timing the XLA reference under the kernel's name."""
     if impl is not None:
         return impl
-    return "pallas" if on_tpu() else "xla"
+    if on_tpu():
+        return "pallas"
+    raise ValueError(
+        f"impl=None selects the Pallas kernel, which needs a TPU (backend "
+        f"is {jax.default_backend()!r}); pass impl='interpret' or 'xla'")
 
 
 @functools.partial(jax.jit, static_argnames=("activation", "impl"))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture x input-shape x mesh) cell:
@@ -22,7 +19,14 @@ roofline benchmark (benchmarks/roofline.py) derives the three terms.
 Usage:
   python -m repro.launch.dryrun --arch llama3.2-1b --shape decode_32k --mesh single
   python -m repro.launch.dryrun --all [--mesh both]
+
+A CPU tool by design: it pins JAX to the host platform (512 fake devices)
+before JAX is imported, so on a machine with a chip it never takes the TPU.
 """
+import os
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import dataclasses
 import json

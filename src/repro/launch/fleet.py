@@ -39,6 +39,7 @@ import jax
 from repro.chaos import FaultPlan, serve_fleet_chaos
 from repro.configs import get_arch
 from repro.fleet import ROUTING_POLICIES, FleetMetrics, serve_fleet
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.stats import check_coverage
 from repro.models import transformer as T
 from repro.models.params import init_params
@@ -254,4 +255,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.exit(main())

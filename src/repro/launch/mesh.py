@@ -11,8 +11,15 @@ state (the dry-run must set XLA_FLAGS before the first jax call).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, **kwargs):
+    """``jax.make_mesh`` with Auto axes. Its default is Explicit, while the
+    sharding rules (``repro.sharding``) place arrays through
+    ``with_sharding_constraint``, which needs Auto axes."""
+    kwargs.setdefault("axis_types", (AxisType.Auto,) * len(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -18,11 +18,21 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.obs import MetricsHub, engine_events, write_chrome_trace
 from repro.serve import ServeConfig, ServeEngine
 from repro.trace import TraceRecorder
+
+
+def init_model(arch: str, *, smoke: bool = False, seed: int = 0):
+    """The served model: the registry config (``.reduced()`` for smoke
+    runs) and random weights made from ``seed``."""
+    cfg = get_arch(arch)
+    if smoke:
+        cfg = cfg.reduced()
+    return cfg, init_params(T.param_defs(cfg), jax.random.PRNGKey(seed))
 
 
 def main(argv=None):
@@ -64,10 +74,7 @@ def main(argv=None):
                          "here")
     args = ap.parse_args(argv)
 
-    cfg = get_arch(args.arch)
-    if args.smoke:
-        cfg = cfg.reduced()
-    params = init_params(T.param_defs(cfg), jax.random.PRNGKey(0))
+    cfg, params = init_model(args.arch, smoke=args.smoke)
     # observability is a pure event-stream consumer: the hub rides the
     # recorder's sink list, so metrics-on serving issues the exact same
     # dispatches and host syncs as metrics-off
@@ -145,4 +152,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -25,6 +25,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_arch
 from repro.data import ByteCorpus, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.models.params import init_params, shardings_for, param_count
@@ -153,4 +154,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
 from repro.models.layers import apply_rope
@@ -344,14 +343,17 @@ def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
         v_att = (v_att.astype(jnp.bfloat16)
                  * jax.lax.slice_in_dim(v_sc, 0, span, axis=2
                                         )[..., None].astype(jnp.bfloat16))
-    # the Pallas kernel needs the chunk grid to tile the span exactly; the
-    # last chunk can overhang the cache (max_len not a multiple of the
-    # chunk) — its overhanging rows are padding, which the XLA twin masks
-    # fine, so route ragged shapes there
-    bq, bkv = min(cfg.chunk_q, C), min(cfg.chunk_kv, C)
-    pallas_ok = (cfg.use_pallas and offset + C == span
-                 and C % bq == 0 and span % bkv == 0)
-    if pallas_ok:
+    if cfg.use_pallas:
+        # the kernel needs the chunk grid to tile the span exactly; a last
+        # chunk that overhangs the cache (max_len not a multiple of the
+        # chunk) is refused rather than quietly sent to the XLA twin
+        bq, bkv = min(cfg.chunk_q, C), min(cfg.chunk_kv, C)
+        if offset + C != span or C % bq or span % bkv:
+            raise ValueError(
+                f"use_pallas: prefill chunk [{offset}, {offset + C}) does "
+                f"not tile the attended span {span} with blocks "
+                f"({bq}, {bkv}); make max_len a multiple of the prefill "
+                f"chunk and the chunk a multiple of chunk_q/chunk_kv")
         from repro.kernels.flash_attention import flash_attention
         o = flash_attention(q, k_att, v_att, causal=True,
                             block_q=bq, block_kv=bkv, q_offset=offset)
@@ -486,10 +488,13 @@ def attention_prefill_packed(cfg: ModelConfig, p: dict, x: jax.Array,
         kv_pos, kv_seg = seg_pos, kv_seg_chunk
 
     seg_info = (seg_pos, q_seg, kv_pos, kv_seg)
-    Skv = k_att.shape[2]
-    bq, bkv = min(cfg.chunk_q, C), min(cfg.chunk_kv, Skv)
-    pallas_ok = cfg.use_pallas and C % bq == 0 and Skv % bkv == 0
-    if pallas_ok:
+    if cfg.use_pallas:
+        Skv = k_att.shape[2]
+        bq, bkv = min(cfg.chunk_q, C), min(cfg.chunk_kv, Skv)
+        if C % bq or Skv % bkv:
+            raise ValueError(
+                f"use_pallas: packed chunk of {C} tokens over {Skv} keys "
+                f"does not tile with blocks ({bq}, {bkv})")
         from repro.kernels.flash_attention import flash_attention
         o = flash_attention(q, k_att, v_att, block_q=bq, block_kv=bkv,
                             segment_info=seg_info)
@@ -594,7 +599,7 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
         o_glob = jax.lax.psum(o * corr[..., None], "model")
         return o_glob / jnp.maximum(l_glob[..., None], 1e-30)
 
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(q_spec, cache_spec, cache_spec, len_spec),
         out_specs=q_spec,

@@ -115,7 +115,6 @@ def apply_moe_ep(cfg: ModelConfig, p: dict, x: jax.Array,
     decode (napkin math in the §Perf log)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
     B, S, d = x.shape
     E, k = cfg.num_experts, cfg.experts_per_token
     info = MeshInfo(mesh)
@@ -180,7 +179,7 @@ def apply_moe_ep(cfg: ModelConfig, p: dict, x: jax.Array,
         y_loc = jax.lax.dynamic_slice_in_dim(y, start * t_loc, t_loc, 0)
         return y_loc.reshape(x_l.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, r_spec, w_in_spec, w_in_spec, w_out_spec),
         out_specs=(x_spec, P()),

@@ -11,6 +11,7 @@ distribution. The same tree drives:
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -64,11 +65,15 @@ def _path_str(path) -> str:
 
 def init_params(defs, key):
     """Materialize a ParamDef tree. Keys are derived per-leaf from the tree
-    path, so adding parameters never reshuffles existing ones."""
+    path, so adding parameters never reshuffles existing ones. The path is
+    hashed with CRC-32, not ``hash()``, whose value for a ``str`` changes
+    from one process to the next: the same key gives the same weights in
+    every process."""
     def one(path, pd):
         if not is_def(pd):
             return pd
-        leaf_key = jax.random.fold_in(key, hash(_path_str(path)) % (2**31))
+        path_hash = zlib.crc32(_path_str(path).encode()) % (2**31)
+        leaf_key = jax.random.fold_in(key, path_hash)
         return _materialize(pd, leaf_key)
 
     return jax.tree_util.tree_map_with_path(one, defs, is_leaf=is_def)

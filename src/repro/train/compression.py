@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def init_error_state(params):
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
@@ -65,10 +63,11 @@ def compressed_grad_allreduce(grads, err_state, mesh: Mesh,
         return gs, es
 
     specs = jax.tree.map(lambda _: P(), grads)  # per-shard full arrays
-    mapped = shard_map(body, mesh=mesh,
-                       in_specs=(specs, specs),
-                       out_specs=jax.tree.map(lambda _: (P(), P()), grads),
-                       check_vma=False)(grads, err_state)
+    mapped = jax.shard_map(body, mesh=mesh,
+                           in_specs=(specs, specs),
+                           out_specs=jax.tree.map(lambda _: (P(), P()),
+                                                  grads),
+                           check_vma=False)(grads, err_state)
     return split(mapped)
 
 

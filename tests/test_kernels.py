@@ -194,3 +194,12 @@ def test_mamba_chunk(B, T, d, n, dt, c):
         wy, wh = ref.mamba_chunk_ref(a[b], u[b], C[b])
         np.testing.assert_allclose(y[b], wy, rtol=1e-4, atol=1e-4)
         np.testing.assert_allclose(h[b], wh, rtol=1e-4, atol=1e-4)
+
+
+def test_ops_auto_mode_refuses_off_tpu():
+    """impl=None means the compiled kernel: off a TPU it raises instead of
+    quietly running the XLA reference under the kernel's name."""
+    from repro.kernels import ops
+    x = jnp.ones((8, 128), jnp.float32)
+    with pytest.raises(ValueError, match="needs a TPU"):
+        ops.layernorm(x, jnp.ones(128), jnp.zeros(128))

@@ -221,3 +221,17 @@ def test_pas_log_records_phases(setup):
     assert "summarization" in phases and "generation" in phases
     for e in eng.pas_log:
         assert e["ffn_route"] in ("gemm", "gemv")
+
+
+def test_pallas_prefill_refuses_ragged_tail(setup):
+    """With use_pallas, a last chunk that overhangs the cache (max_len not a
+    multiple of the chunk) raises rather than running the XLA twin."""
+    import dataclasses
+    import functools
+    cfg, params = setup
+    cfg = dataclasses.replace(cfg, use_pallas=True)
+    cache = init_params(T.cache_defs(cfg, 2, 40), KEY)
+    tokens, valid = np.zeros((2, 16), np.int32), np.ones((2, 16), bool)
+    step = functools.partial(T.prefill_chunk, cfg, offset=32)
+    with pytest.raises(ValueError, match="does not tile"):
+        jax.eval_shape(step, params, tokens, cache, valid)

@@ -8,7 +8,7 @@ from repro.sharding.axes import logical_spec
 
 
 def _mesh(shape, names):
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     return make_mesh(shape, names)
 
 

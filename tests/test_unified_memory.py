@@ -98,7 +98,7 @@ def test_tpu_unified_layout():
     from repro.core.unified_memory import assert_unified_layout
     from repro.models import transformer as T
     from repro.configs import get_arch
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     stats = assert_unified_layout(
         T.param_defs(get_arch("llama3.2-1b").reduced()), mesh)
