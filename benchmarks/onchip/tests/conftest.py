@@ -1,0 +1,9 @@
+"""The benchmark's own tests run on the CPU, at rehearsal sizes:
+
+    JAX_PLATFORMS=cpu python -m pytest -q benchmarks/onchip/tests
+"""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
