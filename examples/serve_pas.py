@@ -20,13 +20,16 @@ from repro.core.cost_model import pim_fc_time, pipelined_mu_time
 from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.serve import ServeConfig, ServeEngine
+from repro.trace import TraceRecorder
 
 
 def main():
     cfg = get_arch("llama3.2-1b").reduced()
     params = init_params(T.param_defs(cfg), jax.random.PRNGKey(0))
+    rec = TraceRecorder()
     eng = ServeEngine(cfg, params, ServeConfig(max_slots=4, max_len=96,
-                                               prefill_chunk=16))
+                                               prefill_chunk=16),
+                      recorder=rec)
 
     rng = np.random.default_rng(0)
     for i in range(10):
@@ -37,14 +40,17 @@ def main():
           f"{sum(map(len, results.values()))} tokens")
     print(f"dispatches: {eng.dispatch_counts['prefill']} batched-prefill, "
           f"{eng.dispatch_counts['decode']} decode")
-    # the paper's two phases, live from the engine's PAS log: summarization
-    # (batched prompt chunks) routes GEMM, generation (small active batch)
-    # routes GEMV — Algorithm 1 picks per phase, not per model
+    # the paper's two phases, live from the route each recorded dispatch
+    # carries: summarization (batched prompt chunks) routes GEMM,
+    # generation (small active batch) routes GEMV — Algorithm 1 picks per
+    # phase, not per model
+    routes = [e["route"] for e in rec.events
+              if e["type"] in ("prefill", "decode")]
     print(f"{'phase':>14} {'tokens':>7} {'ffn_route':>10} {'gemv_path':>10}")
-    for e in eng.pas_log[:8]:
+    for e in routes[:8]:
         print(f"{e['phase']:>14} {e['tokens']:>7} {e['ffn_route']:>10} "
               f"{str(e['gemv_path']):>10}")
-    gen = [e for e in eng.pas_log if e["phase"] == "generation"]
+    gen = [e for e in routes if e["phase"] == "generation"]
     gemv = sum(e["gemv_path"] for e in gen)
     print(f"...\nPAS: {gemv}/{len(gen)} generation steps took the "
           f"GEMV (PIM-analogue) path\n")

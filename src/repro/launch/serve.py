@@ -77,11 +77,10 @@ def main(argv=None):
     cfg, params = init_model(args.arch, smoke=args.smoke)
     # observability is a pure event-stream consumer: the hub rides the
     # recorder's sink list, so metrics-on serving issues the exact same
-    # dispatches and host syncs as metrics-off
-    hub = rec = None
-    if args.metrics_out or args.timeline_out:
-        hub = MetricsHub()
-        rec = TraceRecorder(sinks=[hub])
+    # dispatches and host syncs as metrics-off; the recorder's events also
+    # carry each dispatch's PAS route for the per-phase summary below
+    hub = MetricsHub() if args.metrics_out or args.timeline_out else None
+    rec = TraceRecorder(sinks=[hub] if hub is not None else ())
     eng = ServeEngine(cfg, params,
                       ServeConfig(max_slots=args.slots,
                                   max_len=args.max_len,
@@ -105,8 +104,9 @@ def main(argv=None):
     print(f"[serve] {len(results)} requests, {tokens} tokens "
           f"in {dt:.2f}s ({tokens/dt:.1f} tok/s)")
     by_phase = {}
-    for e in eng.pas_log:
-        by_phase.setdefault(e["phase"], []).append(e)
+    for e in rec.events:
+        if e["type"] in ("prefill", "decode"):
+            by_phase.setdefault(e["route"]["phase"], []).append(e["route"])
     for phase, entries in by_phase.items():
         gemv = sum(1 for e in entries if e["gemv_path"])
         print(f"[serve] PAS {phase}: {len(entries)} steps, "
@@ -132,7 +132,7 @@ def main(argv=None):
           f"{stats['fused']} fused / {stats['overlapped']} overlapped / "
           f"{stats['serialized']} serialized / {stats['decode_only']} "
           f"decode-only steps")
-    if rec is not None:
+    if hub is not None:
         trace = rec.to_trace()          # finalize: summary reaches the hub
         if args.metrics_out:
             with open(args.metrics_out, "w") as f:

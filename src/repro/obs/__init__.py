@@ -1,12 +1,16 @@
-"""repro.obs — serving observability: metrics, SLO summaries, timelines.
+"""repro.obs — serving observability: metrics, SLO summaries, timelines,
+and wall-clock spans.
 
-Host-side only, by construction: everything in this package consumes the
-event dicts a ``trace.TraceRecorder`` emits (live, via ``sinks=``) or a
-recorded ``trace.Trace`` (offline) — never engine or device state — so
-metrics collection adds ZERO dispatches and ZERO host syncs to a serve.
-The ``repro.verify`` host-sync AST lint scans this package along with
-serve/sched, and the zero-overhead test pins dispatch/host-sync counts
-metrics-on vs metrics-off for every policy.
+Host-side only, by construction: nothing in this package reads a device
+array, so observing a serve adds ZERO dispatches and ZERO host syncs.
+``metrics`` and ``timeline`` consume the event dicts a
+``trace.TraceRecorder`` emits (live, via ``sinks=``) or a recorded
+``trace.Trace`` (offline), on the engine-tick clock. ``spans`` is the one
+module the engine itself calls: it times each step's phases on the host
+clock and notes GC pauses and compiles per step. The ``repro.verify``
+host-sync AST lint scans this package along with serve/sched, and the
+zero-overhead tests pin dispatch/host-sync counts metrics-on vs
+metrics-off and profiler-on vs profiler-off for every policy.
 
   metrics   ``MetricsHub``: counter/gauge/histogram registry, per-request
             lifecycle timelines (arrival -> admit -> prefill chunks ->
@@ -19,6 +23,10 @@ metrics-on vs metrics-off for every policy.
             async-fetch flows, per-slot request lanes, queue-depth
             counters, and simulator-replay NPU/PIM stream spans, into one
             ``trace.json``.
+  spans     ``span(log, name)``: a profiler annotation plus a self time in
+            the engine's ``StepLog``, a ring of per-step records (host time
+            by ``serve.*`` phase, GC pauses, backend compiles);
+            ``spans.latest()`` reaches the newest engine's log.
 
 CLI: ``python -m repro.launch.stats <trace.jsonl>`` emits the metrics
 report and timeline for any recorded trace;

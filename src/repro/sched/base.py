@@ -80,6 +80,7 @@ class Scheduler:
             "decode_only": 0,    # decode only
             "idle": 0,           # nothing to do (open-loop clock tick)
         }
+        self.kind: Optional[str] = None   # the last step's kind
 
     def step(self, engine) -> List[Tuple[int, int]]:
         raise NotImplementedError
@@ -87,3 +88,4 @@ class Scheduler:
     def _tick(self, kind: str) -> None:
         self.stats["steps"] += 1
         self.stats[kind] += 1
+        self.kind = kind

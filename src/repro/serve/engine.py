@@ -12,8 +12,9 @@ The engine is the TPU realization of the paper's two-phase inference flow:
     dispatches are issued (double-buffered fetch);
   * PAS (core/pas.py) routes the FC work per step and per phase: below the
     MXU token parallelism the GEMV/streaming path wins (generation), above
-    it the GEMM path wins (summarization) — every step's phase and
-    ``route_fc_tpu`` decision lands in ``pas_log``, the Algorithm-1 twin.
+    it the GEMM path wins (summarization) — every dispatch's phase and
+    ``route_fc_tpu`` decision rides its trace event (``route``), the
+    Algorithm-1 twin.
 
 Step composition is owned by a ``repro.sched`` policy (``ServeConfig.
 policy``): the engine exposes phase primitives — ``admit_wave``,
@@ -63,10 +64,16 @@ every request / admission / prefill-dispatch / decode-step / completion
 event — including each step's sub-batch membership, overlap/fused flags and
 superstep spans — for offline lowering to PAS command streams (see
 repro/trace/).
+
+Wall-clock spans (``repro.obs.spans``) are always on: every step leaves a
+record of its host time by phase (``serve.admit``, ``serve.prefill``,
+``serve.decode``, ``serve.fetch``, ...), its GC pauses and compiles in
+``ServeEngine.spans``, and the same names land in a profiler trace.
 """
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -80,6 +87,7 @@ from repro.configs.base import ModelConfig
 from repro.core.pas import phase_log_entry
 from repro.models import transformer as T
 from repro.models.params import init_params
+from repro.obs.spans import StepLog, span
 from repro.sched import (PackedPrefillJob, PrefillJob, make_scheduler,
                          plan_packed_job)
 
@@ -99,6 +107,12 @@ class Request:
     # the pending snapshot payload until ``admit_wave`` scatters it.
     prefill_start: int = 0
     restore: Optional[dict] = None
+    # lifecycle on the host clock (time.perf_counter seconds): queued,
+    # admitted into a slot, first token resolved, last token resolved
+    t_enqueued: Optional[float] = None
+    t_admitted: Optional[float] = None
+    t_first_token: Optional[float] = None
+    t_done: Optional[float] = None
 
 
 # Jitted entry points are cached at module level keyed by the (frozen,
@@ -281,7 +295,7 @@ class ServeEngine:
                                         map_dims=scfg.map_dims,
                                         max_jobs=scfg.max_prefill_jobs,
                                         decode_floor=scfg.decode_floor)
-        self.pas_log: List[dict] = []
+        self.spans = StepLog()        # per-step host spans (repro.obs)
         # dispatch accounting (benchmarks/serve_prefill.py + serve_decode.py
         # read this): "fused" counts single-dispatch overlapped steps (one
         # program carrying a prefill chunk AND a decode — neither bucket
@@ -367,7 +381,8 @@ class ServeEngine:
                 f"admission queue at capacity ({self.scfg.queue_cap})")
         rid = self._next_rid
         self._next_rid += 1
-        req = Request(rid, prompt, max_new_tokens, gid=gid)
+        req = Request(rid, prompt, max_new_tokens, gid=gid,
+                      t_enqueued=time.perf_counter())
         if restore is not None:
             req.prefill_start = int(restore["prefix_len"])
             req.restore = restore
@@ -383,7 +398,7 @@ class ServeEngine:
     # ---- chaos hooks (repro.chaos) ----------------------------------------- #
     def set_degraded(self, flag: bool) -> None:
         """PIM-degraded mode: while set, every routing decision this engine
-        records (``phase_log_entry`` → pas_log, trace route dicts, and the
+        records (``phase_log_entry`` → trace route dicts, and the
         pim_aware overlap gate) is forced to the NPU/MU path — the node
         keeps serving on normal memory accesses only, it just loses the
         GEMV/PIM side of the crossover. Numerics are untouched: the route
@@ -563,53 +578,60 @@ class ServeEngine:
         free = self.free_slot_ids()
         if not (free and self.queue):
             return []
-        if self.scfg.admission == "bucketed" and len(self.queue) > 1:
-            self.queue.sort(key=lambda r: max(
-                self._chunk_bucket(r) - r.deferred, 0))
-        cap = len(free) if limit is None else min(limit, len(free))
-        admitted: List[Tuple[int, Request]] = []
-        while len(admitted) < cap and self.queue:
-            admitted.append((free.pop(0), self.queue.pop(0)))
-        for r in self.queue:
-            r.deferred += 1
-        sl = jnp.asarray(np.array([s for s, _ in admitted]))
-        # one masked reset for the whole admission batch (cache rows + lens)
-        self.cache = jax.tree.map(lambda leaf: leaf.at[:, sl].set(0),
-                                  self.cache)
-        # The fused decode step writes K/V at lens[slot] for EVERY slot
-        # (inactive ones included) as a dispatch side effect. While a slot
-        # is mid-prefill under an interleaving policy, co-scheduled decode
-        # steps must not clobber its freshly written prompt cache — park its
-        # write cursor at max_len-1, a position generation can never attend
-        # (termination fires before lens reaches it). The sequential prefill
-        # path instead drives ``lens`` itself, so it starts at 0.
-        park = self.scfg.max_len - 1 \
-            if self.effective_prefill_mode == "batched" else 0
-        self.lens = self.lens.at[sl].set(park)
-        self.gen_count = self.gen_count.at[sl].set(0)
-        self.max_new = self.max_new.at[sl].set(jnp.asarray(
-            [r.max_new_tokens for _, r in admitted], jnp.int32))
-        for slot, req in admitted:
-            self.slot_req[slot] = req
-            self.slot_ready[slot] = False
-        # scatter checkpointed KV prefixes AFTER the batch reset: restored
-        # rows land at positions [0, prefix_len) — far below the parked
-        # write cursor — and the suffix prefill's masked writes never touch
-        # them, so co-scheduled decode steps can't clobber the restore
-        restores: List[Tuple[int, int, int]] = []
-        for slot, req in admitted:
-            if req.restore is not None:
-                self.import_kv_snapshot(slot, req.restore, gid=req.gid,
-                                        rid=req.rid)
-                restores.append((slot, req.rid, req.prefill_start))
-                req.restore = None      # payload applied; free the rows
-        self.wave_count += 1
-        if self.recorder is not None:
-            self.recorder.on_admit(
-                self.step_idx,
-                [(int(s), r.rid, int(len(r.prompt))) for s, r in admitted],
-                restores=restores)
-        return admitted
+        with span(self.spans, "serve.admit"):
+            if self.scfg.admission == "bucketed" and len(self.queue) > 1:
+                self.queue.sort(key=lambda r: max(
+                    self._chunk_bucket(r) - r.deferred, 0))
+            cap = len(free) if limit is None else min(limit, len(free))
+            admitted: List[Tuple[int, Request]] = []
+            now = time.perf_counter()   # leaves the queue for a slot
+            while len(admitted) < cap and self.queue:
+                admitted.append((free.pop(0), self.queue.pop(0)))
+            for r in self.queue:
+                r.deferred += 1
+            sl = jnp.asarray(np.array([s for s, _ in admitted]))
+            # one masked reset for the whole admission batch (cache rows +
+            # lens)
+            self.cache = jax.tree.map(lambda leaf: leaf.at[:, sl].set(0),
+                                      self.cache)
+            # The fused decode step writes K/V at lens[slot] for EVERY slot
+            # (inactive ones included) as a dispatch side effect. While a
+            # slot is mid-prefill under an interleaving policy, co-scheduled
+            # decode steps must not clobber its freshly written prompt cache
+            # — park its write cursor at max_len-1, a position generation
+            # can never attend (termination fires before lens reaches it).
+            # The sequential prefill path instead drives ``lens`` itself, so
+            # it starts at 0.
+            park = self.scfg.max_len - 1 \
+                if self.effective_prefill_mode == "batched" else 0
+            self.lens = self.lens.at[sl].set(park)
+            self.gen_count = self.gen_count.at[sl].set(0)
+            self.max_new = self.max_new.at[sl].set(jnp.asarray(
+                [r.max_new_tokens for _, r in admitted], jnp.int32))
+            for slot, req in admitted:
+                self.slot_req[slot] = req
+                self.slot_ready[slot] = False
+                req.t_admitted = now
+            # scatter checkpointed KV prefixes AFTER the batch reset:
+            # restored rows land at positions [0, prefix_len) — far below
+            # the parked write cursor — and the suffix prefill's masked
+            # writes never touch them, so co-scheduled decode steps can't
+            # clobber the restore
+            restores: List[Tuple[int, int, int]] = []
+            for slot, req in admitted:
+                if req.restore is not None:
+                    self.import_kv_snapshot(slot, req.restore, gid=req.gid,
+                                            rid=req.rid)
+                    restores.append((slot, req.rid, req.prefill_start))
+                    req.restore = None      # payload applied; free the rows
+            self.wave_count += 1
+            if self.recorder is not None:
+                self.recorder.on_admit(
+                    self.step_idx,
+                    [(int(s), r.rid, int(len(r.prompt)))
+                     for s, r in admitted],
+                    restores=restores)
+            return admitted
 
     def build_prefill_job(self, wave) -> Optional[PrefillJob]:
         """Lay a wave's prompt tokens out for chunked dispatch. None when
@@ -648,7 +670,7 @@ class ServeEngine:
     def _account_chunk_prefill(self, job: PrefillJob, c: int,
                                vc: np.ndarray, *, overlap: bool,
                                fused: bool) -> None:
-        """Stats + PAS log + trace event for one UNPACKED chunk dispatch
+        """Stats + route + trace event for one UNPACKED chunk dispatch
         (shared by the standalone and fused paths)."""
         B, C = self.scfg.max_slots, job.chunk
         self.prefill_stats["token_slots"] += B * C
@@ -656,7 +678,6 @@ class ServeEngine:
         self.prefill_stats["kv_cells"] += B * (c * C + C)
         entry = self._phase_entry("summarization", int(vc.sum()),
                                   len(job.wave))
-        self.pas_log.append(entry)
         if self.recorder is not None:
             self.recorder.on_prefill(
                 self.step_idx, offset=c * C, chunk=C,
@@ -667,7 +688,7 @@ class ServeEngine:
 
     def _account_packed_prefill(self, job: PackedPrefillJob, d, *,
                                 overlap: bool, fused: bool) -> None:
-        """Stats + PAS log + trace event for one PACKED dispatch (shared by
+        """Stats + route + trace event for one PACKED dispatch (shared by
         the standalone and fused paths)."""
         C = job.chunk
         self.prefill_stats["token_slots"] += d.token_slots
@@ -675,7 +696,6 @@ class ServeEngine:
         self.prefill_stats["kv_cells"] += d.rows * (d.prefix_span + C)
         slots = sorted({int(s) for s in d.seg_slot[d.valid]})
         entry = self._phase_entry("summarization", d.n_valid, len(slots))
-        self.pas_log.append(entry)
         if self.recorder is not None:
             self.recorder.on_prefill(
                 self.step_idx, offset=-1, chunk=C, valid=d.n_valid,
@@ -695,13 +715,14 @@ class ServeEngine:
         vc = job.valid[:, c * C:(c + 1) * C]
         if not vc.any():
             return
-        fn = self._get_prefill_fn(c)
-        self.cache = fn(self.params,
-                        jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
-                        self.cache, jnp.asarray(vc))
-        self.dispatch_counts["prefill"] += 1
-        self._account_chunk_prefill(job, c, vc, overlap=overlap,
-                                    fused=False)
+        with span(self.spans, "serve.prefill"):
+            fn = self._get_prefill_fn(c)
+            self.cache = fn(self.params,
+                            jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
+                            self.cache, jnp.asarray(vc))
+            self.dispatch_counts["prefill"] += 1
+            self._account_chunk_prefill(job, c, vc, overlap=overlap,
+                                        fused=False)
 
     def _dispatch_packed_chunk(self, job: PackedPrefillJob, *,
                                overlap: bool = False) -> None:
@@ -714,25 +735,30 @@ class ServeEngine:
         prompts) so the trace records offset=-1 and the true packing."""
         d = job.dispatches[job.next_chunk]
         job.next_chunk += 1
-        fn = _jit_prefill_packed(self.cfg, d.prefix_span)
-        self.cache = fn(self.params, jnp.asarray(d.tokens), self.cache,
-                        jnp.asarray(d.seg_slot), jnp.asarray(d.seg_pos),
-                        jnp.asarray(d.seg_ids), jnp.asarray(d.valid),
-                        jnp.asarray(d.row_slot), jnp.asarray(d.prefix_len))
-        self.dispatch_counts["prefill"] += 1
-        self._account_packed_prefill(job, d, overlap=overlap, fused=False)
+        with span(self.spans, "serve.prefill"):
+            fn = _jit_prefill_packed(self.cfg, d.prefix_span)
+            self.cache = fn(self.params, jnp.asarray(d.tokens), self.cache,
+                            jnp.asarray(d.seg_slot), jnp.asarray(d.seg_pos),
+                            jnp.asarray(d.seg_ids), jnp.asarray(d.valid),
+                            jnp.asarray(d.row_slot),
+                            jnp.asarray(d.prefix_len))
+            self.dispatch_counts["prefill"] += 1
+            self._account_packed_prefill(job, d, overlap=overlap,
+                                         fused=False)
 
     def finish_prefill(self, wave) -> None:
         """A wave's prompt is fully cached: arm the slots for generation
         (prompt[:-1] filled the cache; the last prompt token is the first
         generation step's input)."""
-        sl = jnp.asarray(np.array([s for s, _ in wave]))
-        plens = np.array([len(r.prompt) for _, r in wave])
-        self.lens = self.lens.at[sl].set(jnp.asarray(plens - 1, jnp.int32))
-        last = np.array([r.prompt[-1] for _, r in wave], np.int32)
-        self.last_tok = self.last_tok.at[sl].set(jnp.asarray(last))
-        for slot, _ in wave:
-            self.slot_ready[slot] = True
+        with span(self.spans, "serve.arm"):
+            sl = jnp.asarray(np.array([s for s, _ in wave]))
+            plens = np.array([len(r.prompt) for _, r in wave])
+            self.lens = self.lens.at[sl].set(
+                jnp.asarray(plens - 1, jnp.int32))
+            last = np.array([r.prompt[-1] for _, r in wave], np.int32)
+            self.last_tok = self.last_tok.at[sl].set(jnp.asarray(last))
+            for slot, _ in wave:
+                self.slot_ready[slot] = True
 
     def prefill_wave(self, wave) -> None:
         """Serial-policy prefill: run the whole wave to completion within
@@ -759,11 +785,12 @@ class ServeEngine:
         teacher-forced decode steps, one dispatch + host sync per token."""
         for slot, req in wave:
             for pos, tok in enumerate(req.prompt[:-1]):
-                t = jnp.zeros((self.scfg.max_slots, 1), jnp.int32
-                              ).at[slot, 0].set(int(tok))
-                _logits, self.cache = self._decode(self.params, t, self.cache,
-                                                   self.lens)
-                self.lens = self.lens.at[slot].add(1)
+                with span(self.spans, "serve.prefill"):
+                    t = jnp.zeros((self.scfg.max_slots, 1), jnp.int32
+                                  ).at[slot, 0].set(int(tok))
+                    _logits, self.cache = self._decode(
+                        self.params, t, self.cache, self.lens)
+                    self.lens = self.lens.at[slot].add(1)
                 self.dispatch_counts["prefill"] += 1
                 # each teacher-forced dispatch computes a (B, 1) grid with
                 # exactly one useful row — count it, or valid-token-fraction
@@ -774,7 +801,6 @@ class ServeEngine:
                     self.scfg.max_slots * (pos + 1)
             n_valid = max(len(req.prompt) - 1, 0)
             entry = self._phase_entry("summarization", n_valid, len(wave))
-            self.pas_log.append(entry)
             if self.recorder is not None and n_valid:
                 self.recorder.on_prefill(
                     self.step_idx, offset=0, chunk=n_valid, valid=n_valid,
@@ -799,11 +825,6 @@ class ServeEngine:
                                self.cfg.d_model, self.cfg.d_ff,
                                force_mu=self.degraded)
 
-    def _log_generation(self, n_tok: int) -> dict:
-        entry = self._phase_entry("generation", n_tok, n_tok)
-        self.pas_log.append(entry)
-        return entry
-
     def _start_fetch(self, fetch) -> None:
         """Double-buffered fetch: start the result's device->host copy at
         dispatch so co-scheduled work overlaps the transfer."""
@@ -820,15 +841,17 @@ class ServeEngine:
         active_np, n_tok = self._ready_active()
         if active_np is None:
             return None
-        entry = self._log_generation(n_tok)
-        (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
-         self._rng) = self._decode_sample(
-            self.params, self.cache, self.last_tok, self.lens,
-            jnp.asarray(active_np), self.gen_count, self.max_new, self._rng)
-        self.dispatch_counts["decode"] += 1
-        self._start_fetch(fetch)
-        return PendingDecode(fetch=fetch, active_np=active_np, n_tok=n_tok,
-                             route=entry, overlap=overlap)
+        with span(self.spans, "serve.decode"):
+            entry = self._phase_entry("generation", n_tok, n_tok)
+            (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
+             self._rng) = self._decode_sample(
+                self.params, self.cache, self.last_tok, self.lens,
+                jnp.asarray(active_np), self.gen_count, self.max_new,
+                self._rng)
+            self.dispatch_counts["decode"] += 1
+            self._start_fetch(fetch)
+            return PendingDecode(fetch=fetch, active_np=active_np, n_tok=n_tok,
+                                 route=entry, overlap=overlap)
 
     def dispatch_fused_step(self, job) -> PendingDecode:
         """Issue ONE dispatch carrying the resident batch's decode AND the
@@ -840,42 +863,44 @@ class ServeEngine:
         active_np, n_tok = self._ready_active()
         assert active_np is not None, \
             "fused step needs a resident decode batch"
-        dentry = self._log_generation(n_tok)
-        C = self.scfg.prefill_chunk
-        common = (self.last_tok, self.lens, jnp.asarray(active_np),
-                  self.gen_count, self.max_new, self._rng)
-        if isinstance(job, PackedPrefillJob):
-            d = job.dispatches[job.next_chunk]
-            job.next_chunk += 1
-            fn = _jit_fused_step_packed(
-                self.cfg, self.scfg.temperature, self.scfg.eos_token,
-                self.scfg.max_len, d.prefix_span)
-            (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
-             self._rng) = fn(
-                self.params, self.cache, jnp.asarray(d.tokens),
-                jnp.asarray(d.seg_slot), jnp.asarray(d.seg_pos),
-                jnp.asarray(d.seg_ids), jnp.asarray(d.valid),
-                jnp.asarray(d.row_slot), jnp.asarray(d.prefix_len), *common)
-            self._account_packed_prefill(job, d, overlap=True, fused=True)
-        else:
-            c = job.next_chunk
-            job.next_chunk += 1
-            vc = job.valid[:, c * C:(c + 1) * C]
-            assert vc.any(), "fused step dispatched an empty prefill chunk"
-            fn = _jit_fused_step(
-                self.cfg, self.scfg.temperature, self.scfg.eos_token,
-                self.scfg.max_len, c * C)
-            (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
-             self._rng) = fn(
-                self.params, self.cache,
-                jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
-                jnp.asarray(vc), *common)
-            self._account_chunk_prefill(job, c, vc, overlap=True,
-                                        fused=True)
-        self.dispatch_counts["fused"] += 1
-        self._start_fetch(fetch)
-        return PendingDecode(fetch=fetch, active_np=active_np, n_tok=n_tok,
-                             route=dentry, overlap=True, fused=True)
+        with span(self.spans, "serve.decode"):
+            dentry = self._phase_entry("generation", n_tok, n_tok)
+            C = self.scfg.prefill_chunk
+            common = (self.last_tok, self.lens, jnp.asarray(active_np),
+                      self.gen_count, self.max_new, self._rng)
+            if isinstance(job, PackedPrefillJob):
+                d = job.dispatches[job.next_chunk]
+                job.next_chunk += 1
+                fn = _jit_fused_step_packed(
+                    self.cfg, self.scfg.temperature, self.scfg.eos_token,
+                    self.scfg.max_len, d.prefix_span)
+                (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
+                 self._rng) = fn(
+                    self.params, self.cache, jnp.asarray(d.tokens),
+                    jnp.asarray(d.seg_slot), jnp.asarray(d.seg_pos),
+                    jnp.asarray(d.seg_ids), jnp.asarray(d.valid),
+                    jnp.asarray(d.row_slot), jnp.asarray(d.prefix_len),
+                    *common)
+                self._account_packed_prefill(job, d, overlap=True, fused=True)
+            else:
+                c = job.next_chunk
+                job.next_chunk += 1
+                vc = job.valid[:, c * C:(c + 1) * C]
+                assert vc.any(), "fused step dispatched an empty prefill chunk"
+                fn = _jit_fused_step(
+                    self.cfg, self.scfg.temperature, self.scfg.eos_token,
+                    self.scfg.max_len, c * C)
+                (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
+                 self._rng) = fn(
+                    self.params, self.cache,
+                    jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
+                    jnp.asarray(vc), *common)
+                self._account_chunk_prefill(job, c, vc, overlap=True,
+                                            fused=True)
+            self.dispatch_counts["fused"] += 1
+            self._start_fetch(fetch)
+            return PendingDecode(fetch=fetch, active_np=active_np, n_tok=n_tok,
+                                 route=dentry, overlap=True, fused=True)
 
     def dispatch_decode_superstep(self, k: int
                                   ) -> Optional[PendingSuperstep]:
@@ -890,25 +915,35 @@ class ServeEngine:
         active_np, n_tok = self._ready_active()
         if active_np is None:
             return None
-        entry = self._log_generation(n_tok)
-        fn = _jit_decode_superstep(self.cfg, self.scfg.temperature,
-                                   self.scfg.eos_token, self.scfg.max_len, k)
-        (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
-         self._rng) = fn(
-            self.params, self.cache, self.last_tok, self.lens,
-            jnp.asarray(active_np), self.gen_count, self.max_new, self._rng)
-        self.dispatch_counts["decode"] += 1
-        self._start_fetch(fetch)
-        sid = self._superstep_seq
-        self._superstep_seq += 1
-        return PendingSuperstep(fetch=fetch, active_np=active_np, k=k,
-                                route=entry, sid=sid)
+        with span(self.spans, "serve.decode"):
+            entry = self._phase_entry("generation", n_tok, n_tok)
+            fn = _jit_decode_superstep(self.cfg, self.scfg.temperature,
+                                       self.scfg.eos_token,
+                                       self.scfg.max_len, k)
+            (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
+             self._rng) = fn(
+                self.params, self.cache, self.last_tok, self.lens,
+                jnp.asarray(active_np), self.gen_count, self.max_new,
+                self._rng)
+            self.dispatch_counts["decode"] += 1
+            self._start_fetch(fetch)
+            sid = self._superstep_seq
+            self._superstep_seq += 1
+            return PendingSuperstep(fetch=fetch, active_np=active_np, k=k,
+                                    route=entry, sid=sid)
+
+    @staticmethod
+    def _append_token(req: Request, tok: int, now: float) -> None:
+        if not req.generated:
+            req.t_first_token = now
+        req.generated.append(tok)
 
     def _finish_slot(self, i: int) -> None:
         """Retire a slot whose request just terminated: free it, record the
         completion (shared by single-step and superstep resolve)."""
         r = self.slot_req[i]
         r.done = True
+        r.t_done = time.perf_counter()
         self.slot_req[i] = None
         self.slot_ready[i] = False
         if self.recorder is not None:
@@ -927,26 +962,30 @@ class ServeEngine:
         """Materialize a dispatched decode step's (token, done, len) triple
         — the step's single blocking host sync — and apply its results:
         token append, trace events, completions."""
-        fetch_np = np.asarray(pending.fetch)
+        with span(self.spans, "serve.fetch"):
+            fetch_np = np.asarray(pending.fetch)
         self.host_syncs += 1
-        toks_np, done_np, lens_np = (fetch_np[0], fetch_np[1].astype(bool),
-                                     fetch_np[2])
-        active_idx = np.nonzero(pending.active_np)[0]
-        out = [(self.slot_req[i].rid, int(toks_np[i])) for i in active_idx]
-        for i, (rid, tok) in zip(active_idx, out):
-            self.slot_req[i].generated.append(tok)
-        if self.recorder is not None:
-            # decode event first: completions reference the token it carries
-            self.recorder.on_decode(
-                self.step_idx, occupancy=pending.n_tok,
-                slot_lens=[int(x) for x in lens_np],
-                slots=[int(i) for i in active_idx],
-                tokens=list(out), route=pending.route,
-                overlap=pending.overlap, fused=pending.fused)
-        for i in active_idx:
-            if done_np[i]:
-                self._finish_slot(i)
-        return out
+        with span(self.spans, "serve.apply"):
+            now = time.perf_counter()
+            toks_np, done_np, lens_np = (
+                fetch_np[0], fetch_np[1].astype(bool), fetch_np[2])
+            active_idx = np.nonzero(pending.active_np)[0]
+            out = [(self.slot_req[i].rid, int(toks_np[i]))
+                   for i in active_idx]
+            for i, (rid, tok) in zip(active_idx, out):
+                self._append_token(self.slot_req[i], tok, now)
+            if self.recorder is not None:
+                # decode event first: completions reference its token
+                self.recorder.on_decode(
+                    self.step_idx, occupancy=pending.n_tok,
+                    slot_lens=[int(x) for x in lens_np],
+                    slots=[int(i) for i in active_idx],
+                    tokens=list(out), route=pending.route,
+                    overlap=pending.overlap, fused=pending.fused)
+            for i in active_idx:
+                if done_np[i]:
+                    self._finish_slot(i)
+            return out
 
     def resolve_decode_superstep(self, pending: PendingSuperstep
                                  ) -> List[Tuple[int, int]]:
@@ -957,46 +996,52 @@ class ServeEngine:
         fire at the inner step where the lane terminated, and the engine
         clock advances one step per inner step so open-loop arrival timing
         stays one-decode-round-per-tick."""
-        fetch_np = np.asarray(pending.fetch)      # (k, 3, B)
+        with span(self.spans, "serve.fetch"):
+            fetch_np = np.asarray(pending.fetch)      # (k, 3, B)
         self.host_syncs += 1
-        out: List[Tuple[int, int]] = []
-        active = pending.active_np.copy()
-        for i in range(pending.k):
-            if i:
-                self.step_idx += 1     # inner steps advance the timeline
-            idx = np.nonzero(active)[0]
-            if idx.size == 0:
-                continue               # lanes drained early; clock still ran
-            toks_np = fetch_np[i, 0]
-            done_np = fetch_np[i, 1].astype(bool)
-            lens_np = fetch_np[i, 2]
-            step_out = [(self.slot_req[s].rid, int(toks_np[s]))
-                        for s in idx]
-            for s, (_rid, tok) in zip(idx, step_out):
-                self.slot_req[s].generated.append(tok)
-            self.superstep_tokens += 1
-            if self.recorder is not None:
-                self.recorder.on_decode(
-                    self.step_idx, occupancy=int(idx.size),
-                    slot_lens=[int(x) for x in lens_np],
-                    slots=[int(s) for s in idx],
-                    tokens=list(step_out), route=pending.route,
-                    overlap=False, superstep=pending.k,
-                    superstep_id=pending.sid)
-            for s in idx:
-                if done_np[s]:
-                    self._finish_slot(s)
-            active &= ~done_np
-            out.extend(step_out)
-        return out
+        with span(self.spans, "serve.apply"):
+            now = time.perf_counter()
+            out: List[Tuple[int, int]] = []
+            active = pending.active_np.copy()
+            for i in range(pending.k):
+                if i:
+                    self.step_idx += 1   # inner steps advance the timeline
+                idx = np.nonzero(active)[0]
+                if idx.size == 0:
+                    continue             # lanes drained early; clock ran
+                toks_np = fetch_np[i, 0]
+                done_np = fetch_np[i, 1].astype(bool)
+                lens_np = fetch_np[i, 2]
+                step_out = [(self.slot_req[s].rid, int(toks_np[s]))
+                            for s in idx]
+                for s, (_rid, tok) in zip(idx, step_out):
+                    self._append_token(self.slot_req[s], tok, now)
+                self.superstep_tokens += 1
+                if self.recorder is not None:
+                    self.recorder.on_decode(
+                        self.step_idx, occupancy=int(idx.size),
+                        slot_lens=[int(x) for x in lens_np],
+                        slots=[int(s) for s in idx],
+                        tokens=list(step_out), route=pending.route,
+                        overlap=False, superstep=pending.k,
+                        superstep_id=pending.sid)
+                for s in idx:
+                    if done_np[s]:
+                        self._finish_slot(s)
+                active &= ~done_np
+                out.extend(step_out)
+            return out
 
     # ---- step: composition delegated to the scheduling policy --------------- #
     def step(self) -> List[Tuple[int, int]]:
         if self.halted:
             raise RuntimeError("engine is halted (crashed node); a crashed "
                                "replica must never dispatch again")
-        out = self.scheduler.step(self)
+        step = self.step_idx
+        with span(self.spans, "serve.step", step=step):
+            out = self.scheduler.step(self)
         self.step_idx += 1     # idle steps still advance the timeline
+        self.spans.record(step, self.step_idx - step, self.scheduler.kind)
         return out             # (open-loop arrival processes need a clock)
 
     def run_until_done(self, max_steps: int = 10_000) -> Dict[int, List[int]]:

@@ -7,14 +7,23 @@ from repro.configs import get_arch
 from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.serve import ServeConfig, ServeEngine
+from repro.trace import TraceRecorder
 
 KEY = jax.random.PRNGKey(0)
 
 
-def _engine(cfg, params, mode="batched", chunk=8, slots=3, max_len=64):
+def _engine(cfg, params, mode="batched", chunk=8, slots=3, max_len=64,
+            recorder=None):
     return ServeEngine(cfg, params,
                        ServeConfig(max_slots=slots, max_len=max_len,
-                                   prefill_mode=mode, prefill_chunk=chunk))
+                                   prefill_mode=mode, prefill_chunk=chunk),
+                       recorder=recorder)
+
+
+def _routes(rec):
+    """The PAS route of every recorded prefill and decode event."""
+    return [e["route"] for e in rec.events
+            if e["type"] in ("prefill", "decode")]
 
 
 @pytest.fixture(scope="module")
@@ -132,12 +141,13 @@ def test_decode_is_single_dispatch_single_sync(setup):
     into the jitted decode step, so a generation step costs exactly ONE
     dispatch and ONE host sync (the token/done/len fetch)."""
     cfg, params = setup
-    eng = _engine(cfg, params)
+    rec = TraceRecorder()
+    eng = _engine(cfg, params, recorder=rec)
     rng = np.random.default_rng(7)
     for p in (4, 11, 2):
         eng.add_request(rng.integers(0, cfg.vocab_size, p), max_new_tokens=5)
     eng.run_until_done()
-    gen_steps = sum(e["phase"] == "generation" for e in eng.pas_log)
+    gen_steps = sum(r["phase"] == "generation" for r in _routes(rec))
     assert eng.dispatch_counts["decode"] == gen_steps
     assert eng.host_syncs == gen_steps
 
@@ -212,15 +222,18 @@ def test_bucketed_admission_ages_long_prompts(setup):
 
 
 def test_pas_log_records_phases(setup):
+    """Every recorded dispatch carries its phase and PAS route."""
     cfg, params = setup
-    eng = _engine(cfg, params)
+    rec = TraceRecorder()
+    eng = _engine(cfg, params, recorder=rec)
     rng = np.random.default_rng(4)
     eng.add_request(rng.integers(0, cfg.vocab_size, 12), max_new_tokens=3)
     eng.run_until_done()
-    phases = [e["phase"] for e in eng.pas_log]
+    routes = _routes(rec)
+    phases = [r["phase"] for r in routes]
     assert "summarization" in phases and "generation" in phases
-    for e in eng.pas_log:
-        assert e["ffn_route"] in ("gemm", "gemv")
+    for r in routes:
+        assert r["ffn_route"] in ("gemm", "gemv")
 
 
 def test_pallas_prefill_refuses_ragged_tail(setup):

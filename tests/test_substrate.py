@@ -17,6 +17,7 @@ from repro.models.ssm import chunked_linear_scan
 from repro.optim import adamw_init, adamw_update
 from repro.optim.adafactor import adafactor_init, adafactor_update
 from repro.serve import ServeConfig, ServeEngine
+from repro.trace import TraceRecorder
 
 KEY = jax.random.PRNGKey(0)
 
@@ -130,14 +131,18 @@ def test_checkpoint_roundtrip_bf16_exact():
 def test_serve_continuous_batching_more_requests_than_slots():
     cfg = get_arch("llama3.2-1b").reduced()
     params = init_params(T.param_defs(cfg), KEY)
-    eng = ServeEngine(cfg, params, ServeConfig(max_slots=2, max_len=64))
+    rec = TraceRecorder()
+    eng = ServeEngine(cfg, params, ServeConfig(max_slots=2, max_len=64),
+                      recorder=rec)
     rng = np.random.default_rng(0)
     rids = [eng.add_request(rng.integers(0, cfg.vocab_size, 3),
                             max_new_tokens=4) for _ in range(5)]
     res = eng.run_until_done()
     assert sorted(res) == sorted(rids)
     assert all(len(v) == 4 for v in res.values())
-    assert all(e["active"] <= 2 for e in eng.pas_log)
+    routes = [e["route"] for e in rec.events
+              if e["type"] in ("prefill", "decode")]
+    assert routes and all(r["active"] <= 2 for r in routes)
 
 
 def test_serve_greedy_deterministic():

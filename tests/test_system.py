@@ -101,10 +101,15 @@ def test_compressed_allreduce_error_feedback():
 def test_pas_serving_integration():
     """The serving loop consults the PAS cost model every step."""
     from repro.serve import ServeConfig, ServeEngine
+    from repro.trace import TraceRecorder
     cfg = get_arch("llama3.2-1b").reduced()
     params = init_params(T.param_defs(cfg), KEY)
-    eng = ServeEngine(cfg, params, ServeConfig(max_slots=2, max_len=32))
+    rec = TraceRecorder()
+    eng = ServeEngine(cfg, params, ServeConfig(max_slots=2, max_len=32),
+                      recorder=rec)
     eng.add_request([1, 2], max_new_tokens=3)
     eng.run_until_done()
-    assert eng.pas_log
-    assert all(e["gemv_path"] for e in eng.pas_log)  # tiny batches -> GEMV
+    routes = [e["route"] for e in rec.events
+              if e["type"] in ("prefill", "decode")]
+    assert routes
+    assert all(r["gemv_path"] for r in routes)  # tiny batches -> GEMV
