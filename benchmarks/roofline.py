@@ -62,8 +62,7 @@ def memory_bytes_analytic(arch: str, shape_name: str,
     ~10-40x. This model counts what a TPU actually moves:
 
       decode:   weights streamed once (+FSDP gather reads), KV cache read,
-                cache write (FULL cache for the one-hot baseline update —
-                the documented baseline inefficiency, see §Perf).
+                one new K/V row per slot and layer written in place.
       prefill:  weights once + per-layer activation traffic at fusion
                 granularity + flash-attention KV re-reads (nq passes).
       train:    prefill traffic x3 (fwd + remat recompute + bwd) + grad
@@ -99,8 +98,7 @@ def memory_bytes_analytic(arch: str, shape_name: str,
             weights = params_b - expert_b * (1 - active_frac)
         if cfg.fsdp_params and cfg.moe_impl != "ep":
             weights *= 2.0         # resident read + gathered write
-        kv_write = cache_b if cfg.kv_update == "onehot" else \
-            2 * n_attn * B * cfg.kv_dim * kv_bpe
+        kv_write = 2 * n_attn * B * cfg.kv_dim * kv_bpe
         return weights + cache_b + kv_write
 
     S = shape.seq_len

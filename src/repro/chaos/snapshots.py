@@ -41,9 +41,9 @@ import numpy as np
 
 from repro.checkpoint.store import atomic_save_arrays, load_arrays
 
-# after the slot axis is removed from a (layers, slot, kv_heads, kv_seq,
-# ...) cache leaf, the kv_seq axis — the delta concatenation axis — is 2
-_SEQ_AXIS = 2
+# after the slot axis is removed from a (layers, slot, kv_seq, kv_heads,
+# ...) cache leaf, the kv_seq axis — the delta concatenation axis — is 1
+_SEQ_AXIS = 1
 
 _META_KEYS = ("plen", "generated", "max_new", "last_tok", "lens", "rng")
 

@@ -78,7 +78,6 @@ class ModelConfig:
     flash_vjp: bool = False    # flash BACKWARD (custom VJP): recompute score
                                # blocks in bwd instead of saving scan carries
     ssm_chunk: int = 128       # chunked scan block for rwkv/mamba
-    kv_update: str = "onehot"  # "onehot" (naive baseline) | "scatter" (O(1) bytes)
     kv_dtype: str = "bf16"     # "bf16" | "int8" (quantized KV cache: halves
                                # decode HBM traffic; per-insert scales)
     rules_profile: str = "tp"  # sharding profile: "tp" | "dp" (see axes.py)

@@ -11,7 +11,13 @@ Paper mapping (DESIGN.md §2):
     (shard_map flash-decode) — the TPU version of the paper's "schedule
     around the shared-memory conflict".
   * head-split/merge with zero data reordering (paper §4.2.1) -> einsum
-    layouts keep (B, H, S, D) end-to-end; no transposes materialize.
+    layouts keep queries (B, H, S, D) end-to-end; no transposes materialize.
+  * the KV cache is position-major: one stacked leaf per attention position
+    of the superblock, (layers, slots, S_max, KH, hd), so one token's K/V is
+    one contiguous (KH, hd) row. K/V leave the projection in that layout,
+    and the step programs carry the stacked leaf through the layer loop and
+    write only the rows they fill, at (layer, slot, position) — with the
+    leaf donated, XLA updates it in place and no program copies the cache.
 """
 from __future__ import annotations
 
@@ -26,7 +32,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.configs.base import ModelConfig
 from repro.models.params import ParamDef
 from repro.models.layers import apply_rope
-from repro.sharding.axes import MeshInfo, constrain, logical_spec, _current_mesh
+from repro.sharding.axes import constrain, logical_spec, _current_mesh
 
 NEG_INF = -1e30
 
@@ -53,15 +59,17 @@ def attn_defs(cfg: ModelConfig, stacked: Optional[int] = None,
 # --------------------------------------------------------------------------- #
 def qkv_project(cfg: ModelConfig, p: dict, x: jax.Array,
                 positions: Optional[jax.Array], rope: bool = True):
+    """q: (B, H, S, hd); k, v position-major (B, S, KH, hd), the layout the
+    KV cache stores."""
     q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"])
-    k = jnp.einsum("bsd,dhk->bhsk", x, p["wk"])
-    v = jnp.einsum("bsd,dhk->bhsk", x, p["wv"])
+    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     q = constrain(q, ("batch", "heads", "seq", "head_dim"))
-    k = constrain(k, ("batch", "kv_heads", "seq", "head_dim"))
-    v = constrain(v, ("batch", "kv_heads", "seq", "head_dim"))
+    k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
+    v = constrain(v, ("batch", "seq", "kv_heads", "head_dim"))
     if rope and positions is not None:
         q = apply_rope(q, positions[:, None, :], cfg.rope_theta)
-        k = apply_rope(k, positions[:, None, :], cfg.rope_theta)
+        k = apply_rope(k, positions[:, :, None], cfg.rope_theta)
     return q, k, v
 
 
@@ -78,10 +86,12 @@ def out_project(p: dict, attn_out: jax.Array) -> jax.Array:
 def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool, chunk_q: int, chunk_kv: int,
                         q_offset: int = 0, segment_info=None,
-                        return_lse: bool = False):
+                        return_lse: bool = False, kv_seq_axis: int = 2):
     """Online-softmax blocked attention.
 
-    q: (B, H, Sq, hd); k, v: (B, KH, Skv, hd). GQA via head grouping.
+    q: (B, H, Sq, hd); k, v: (B, KH, Skv, hd), or position-major
+    (B, Skv, KH, hd) with ``kv_seq_axis=1`` (the cache's layout, read in
+    place). GQA via head grouping.
     Scans over query blocks (outer) and KV blocks (inner); O(Sq/cq * Skv/ckv)
     loop nest with O(B*H*cq*ckv) live scores — 32k prefill fits on-chip.
 
@@ -92,7 +102,8 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
     identical structure for CPU tests).
     """
     B, H, Sq, hd = q.shape
-    KH, Skv = k.shape[1], k.shape[2]
+    Skv, KH = k.shape[kv_seq_axis], k.shape[3 - kv_seq_axis]
+    kv = "bkch" if kv_seq_axis == 2 else "bckh"
     G = H // KH
     scale = 1.0 / math.sqrt(hd)
 
@@ -125,9 +136,9 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
         def kv_block(acc, ki):
             o, m, l = acc
-            kb = jax.lax.dynamic_slice_in_dim(k, ki * ckv, ckv, axis=2)  # (B,KH,ckv,hd)
-            vb = jax.lax.dynamic_slice_in_dim(v, ki * ckv, ckv, axis=2)
-            s = jnp.einsum("bkgqh,bkch->bkgqc", qb, kb.astype(jnp.float32))
+            kb = jax.lax.dynamic_slice_in_dim(k, ki * ckv, ckv, kv_seq_axis)
+            vb = jax.lax.dynamic_slice_in_dim(v, ki * ckv, ckv, kv_seq_axis)
+            s = jnp.einsum(f"bkgqh,{kv}->bkgqc", qb, kb.astype(jnp.float32))
             if segment_info is not None:
                 kp = jax.lax.dynamic_slice_in_dim(skv_pos, ki * ckv, ckv, 1)
                 ks = jax.lax.dynamic_slice_in_dim(skv_seg, ki * ckv, ckv, 1)
@@ -143,7 +154,7 @@ def flash_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array, *,
             corr = jnp.exp(m - m_new)
             l_new = l * corr + jnp.sum(p, axis=-1)
             o_new = o * corr[..., None] + jnp.einsum(
-                "bkgqc,bkch->bkgqh", p, vb.astype(jnp.float32))
+                f"bkgqc,{kv}->bkgqh", p, vb.astype(jnp.float32))
             return (o_new, m_new, l_new), None
 
         o0 = jnp.zeros((B, KH, G, cq, hd), jnp.float32)
@@ -260,6 +271,7 @@ flash_attention_fused.defvjp(_flash_fwd, _flash_bwd)
 def attention_prefill(cfg: ModelConfig, p: dict, x: jax.Array,
                       positions: jax.Array, *, causal: bool = True) -> jax.Array:
     q, k, v = qkv_project(cfg, p, x, positions)
+    k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
     if cfg.flash_vjp:
         o = flash_attention_fused(q, k, v, causal, cfg.chunk_q, cfg.chunk_kv)
     else:
@@ -270,79 +282,95 @@ def attention_prefill(cfg: ModelConfig, p: dict, x: jax.Array,
 
 
 # --------------------------------------------------------------------------- #
+# The stacked KV cache: rows written in place, layers read by index
+# --------------------------------------------------------------------------- #
+def _write_rows(leaf: jax.Array, layer, slot, pos, rows: jax.Array):
+    """Write ``rows`` into a stacked cache leaf at (layer, slot, pos).
+
+    leaf: (layers, slots, S_max, ...); ``slot``/``pos`` broadcast to the
+    rows' leading shape and ``rows`` holds one (KH, hd) K/V row (or KH
+    int8 scales) per index. A position of S_max or more is dropped, which
+    is how padding and non-admitted slots leave the cache untouched. Only
+    the named rows are written: a carried, donated leaf stays in place."""
+    return leaf.at[layer, slot, pos].set(rows.astype(leaf.dtype),
+                                         mode="drop")
+
+
+def _dequantize(q: jax.Array, scale: jax.Array) -> jax.Array:
+    return q.astype(jnp.bfloat16) * scale[..., None].astype(jnp.bfloat16)
+
+
+def _store_kv(cfg: ModelConfig, cache: dict, put, k_new: jax.Array,
+              v_new: jax.Array):
+    """Write new K/V rows into the stacked cache with ``put(leaf, rows)``;
+    an int8 cache stores them quantized, with one scale per row. Returns
+    the new cache and the K/V as the cache now holds them (through the
+    int8 round trip), for a chunk that attends its own tokens."""
+    if cfg.kv_dtype != "int8":
+        cache = dict(cache, k=put(cache["k"], k_new), v=put(cache["v"], v_new))
+        return cache, (k_new, v_new)
+    kq, ks = _quantize_kv(k_new)
+    vq, vs = _quantize_kv(v_new)
+    cache = dict(cache, k=put(cache["k"], kq), v=put(cache["v"], vq),
+                 k_scale=put(cache["k_scale"], ks),
+                 v_scale=put(cache["v_scale"], vs))
+    return cache, (_dequantize(kq, ks), _dequantize(vq, vs))
+
+
+def _layer_kv(cfg: ModelConfig, cache: dict, layer,
+              span: Optional[int] = None, slots: Optional[jax.Array] = None):
+    """Layer ``layer``'s K/V, position-major (B, span, KH, hd): positions
+    [0, span) (all when None) of every slot, or of the ``slots`` rows
+    gathered; an int8 cache is dequantized after the gather."""
+    def read(name):
+        x = jax.lax.dynamic_index_in_dim(cache[name], layer, 0, keepdims=False)
+        if span is not None:
+            x = jax.lax.slice_in_dim(x, 0, span, axis=1)
+        return x if slots is None else jnp.take(x, slots, axis=0)
+    k, v = read("k"), read("v")
+    if cfg.kv_dtype == "int8":
+        k, v = _dequantize(k, read("k_scale")), _dequantize(v, read("v_scale"))
+    return k, v
+
+
+def _pallas_flash(q, k, v, **kw):
+    """The Pallas flash kernel over position-major K/V (it reads them
+    head-major, so the attended span is transposed first)."""
+    from repro.kernels.flash_attention import flash_attention
+    return flash_attention(q, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                           **kw)
+
+
+# --------------------------------------------------------------------------- #
 # Batched serving prefill (summarization stage): whole prompt chunks through
 # the flash path, K/V written into the slot cache in one shot
 # --------------------------------------------------------------------------- #
-def write_kv_chunk(k_cache: jax.Array, v_cache: jax.Array,
-                   k_new: jax.Array, v_new: jax.Array,
-                   tok_valid: jax.Array, offset: int):
-    """Scatter a chunk's K/V into the slot cache.
-
-    k_new/v_new: (B, KH, C, hd) — token j of row b lands at cache position
-    offset + j. ``tok_valid`` (B, C) masks padding (per-slot prompt ends and
-    non-admitted slots): invalid writes are dropped, so other slots' cache
-    rows are untouched — unlike the one-token decode update, which clobbers
-    every row's cur_len position."""
-    B, KH, C, hd = k_new.shape
-    L = k_cache.shape[2]
-    pos = jnp.where(tok_valid, offset + jnp.arange(C)[None, :], L)     # (B, C)
-    b_idx = jnp.arange(B)[:, None]
-    k_cache = k_cache.at[b_idx, :, pos].set(
-        jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[b_idx, :, pos].set(
-        jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype), mode="drop")
-    return k_cache, v_cache
-
-
-def _write_scale_chunk(scale_cache: jax.Array, scale_new: jax.Array,
-                       tok_valid: jax.Array, offset: int) -> jax.Array:
-    """scale_cache: (B, KH, L); scale_new: (B, KH, C)."""
-    B, KH, C = scale_new.shape
-    L = scale_cache.shape[2]
-    pos = jnp.where(tok_valid, offset + jnp.arange(C)[None, :], L)
-    b_idx = jnp.arange(B)[:, None]
-    return scale_cache.at[b_idx, :, pos].set(
-        jnp.swapaxes(scale_new, 1, 2), mode="drop")
-
-
 def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
-                             cache: dict, tok_valid: jax.Array,
+                             cache: dict, layer, tok_valid: jax.Array,
                              offset: int):
     """One prefill chunk against the slot cache. x: (B, C, d) at global
-    positions [offset, offset+C). Writes the chunk's K/V into the cache and
-    attends causally over cache[:offset+C] via the flash path — one dispatch
-    covers every admitted slot's chunk instead of B*C decode steps.
+    positions [offset, offset+C); ``cache`` holds this attention position's
+    stacked leaves and ``layer`` indexes them. Writes the chunk's K/V rows
+    into the cache (``tok_valid`` (B, C) False drops a write, so other
+    slots' rows are untouched) and attends causally over
+    cache[:offset+C] via the flash path — one dispatch covers every
+    admitted slot's chunk instead of B*C decode steps.
 
     Returns (out (B, C, d), new_cache). Padding rows (tok_valid False)
-    produce garbage outputs over zero K/V — callers discard them; their
-    cache writes are dropped."""
+    produce garbage outputs over zero K/V — callers discard them."""
     B, C, _ = x.shape
+    S = cache["k"].shape[2]
     positions = offset + jnp.broadcast_to(jnp.arange(C)[None], (B, C))
     q, k_new, v_new = qkv_project(cfg, p, x, positions)
-    new_cache = {}
-    if cfg.kv_dtype == "int8":
-        kq, ks = _quantize_kv(k_new)                 # scales (B, KH, C)
-        vq, vs = _quantize_kv(v_new)
-        k_cache, v_cache = write_kv_chunk(cache["k"], cache["v"], kq, vq,
-                                          tok_valid, offset)
-        k_sc = _write_scale_chunk(cache["k_scale"], ks, tok_valid, offset)
-        v_sc = _write_scale_chunk(cache["v_scale"], vs, tok_valid, offset)
-        new_cache.update(k_scale=k_sc, v_scale=v_sc)
-    else:
-        k_cache, v_cache = write_kv_chunk(cache["k"], cache["v"],
-                                          k_new, v_new, tok_valid, offset)
+    pos = jnp.where(tok_valid, positions, S)
+    slot = jnp.arange(B)[:, None]
+    cache, _ = _store_kv(
+        cfg, cache, lambda leaf, r: _write_rows(leaf, layer, slot, pos, r),
+        k_new, v_new)
     # attend over the populated prefix only — the span is static (chunk
     # index is baked into the jitted function), so this is a free slice
-    span = min(offset + C, k_cache.shape[2])
-    k_att = jax.lax.slice_in_dim(k_cache, 0, span, axis=2)
-    v_att = jax.lax.slice_in_dim(v_cache, 0, span, axis=2)
-    if cfg.kv_dtype == "int8":
-        k_att = (k_att.astype(jnp.bfloat16)
-                 * jax.lax.slice_in_dim(k_sc, 0, span, axis=2
-                                        )[..., None].astype(jnp.bfloat16))
-        v_att = (v_att.astype(jnp.bfloat16)
-                 * jax.lax.slice_in_dim(v_sc, 0, span, axis=2
-                                        )[..., None].astype(jnp.bfloat16))
+    span = min(offset + C, S)
+    k_att, v_att = _layer_kv(cfg, cache, layer, span)
     if cfg.use_pallas:
         # the kernel needs the chunk grid to tile the span exactly; a last
         # chunk that overhangs the cache (max_len not a multiple of the
@@ -354,16 +382,13 @@ def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
                 f"not tile the attended span {span} with blocks "
                 f"({bq}, {bkv}); make max_len a multiple of the prefill "
                 f"chunk and the chunk a multiple of chunk_q/chunk_kv")
-        from repro.kernels.flash_attention import flash_attention
-        o = flash_attention(q, k_att, v_att, causal=True,
-                            block_q=bq, block_kv=bkv, q_offset=offset)
+        o = _pallas_flash(q, k_att, v_att, causal=True, block_q=bq,
+                          block_kv=bkv, q_offset=offset)
     else:
         o = flash_attention_xla(q, k_att, v_att, causal=True,
                                 chunk_q=cfg.chunk_q, chunk_kv=cfg.chunk_kv,
-                                q_offset=offset)
-    out = out_project(p, o)
-    new_cache.update(k=k_cache, v=v_cache)
-    return out, new_cache
+                                q_offset=offset, kv_seq_axis=1)
+    return out_project(p, o), cache
 
 
 # --------------------------------------------------------------------------- #
@@ -371,42 +396,8 @@ def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
 # of a long one) — per-token (slot, position) K/V scatter, per-row cache
 # prefix gather, segment-masked flash attention
 # --------------------------------------------------------------------------- #
-def write_kv_packed(k_cache: jax.Array, v_cache: jax.Array,
-                    k_new: jax.Array, v_new: jax.Array,
-                    seg_slot: jax.Array, seg_pos: jax.Array,
-                    tok_valid: jax.Array):
-    """Scatter a PACKED chunk's K/V into the slot cache.
-
-    k_new/v_new: (R, KH, C, hd) — token j of lane r lands at cache row
-    ``seg_slot[r, j]``, position ``seg_pos[r, j]`` (the generalization of
-    ``write_kv_chunk``'s row-is-slot / position-is-offset+j layout; the
-    lane count R is decoupled from the cache's slot count). Invalid tokens
-    (padding between packed segments) are dropped. The packing planner
-    covers every prompt position exactly once, so no two tokens of one
-    dispatch scatter to the same (slot, position) cell."""
-    L = k_cache.shape[2]
-    pos = jnp.where(tok_valid, seg_pos, L)                  # (B, C): L drops
-    slot = jnp.where(tok_valid, seg_slot, 0)
-    k_cache = k_cache.at[slot, :, pos].set(
-        jnp.swapaxes(k_new, 1, 2).astype(k_cache.dtype), mode="drop")
-    v_cache = v_cache.at[slot, :, pos].set(
-        jnp.swapaxes(v_new, 1, 2).astype(v_cache.dtype), mode="drop")
-    return k_cache, v_cache
-
-
-def _write_scale_packed(scale_cache: jax.Array, scale_new: jax.Array,
-                        seg_slot: jax.Array, seg_pos: jax.Array,
-                        tok_valid: jax.Array) -> jax.Array:
-    """scale_cache: (B, KH, L); scale_new: (B, KH, C)."""
-    L = scale_cache.shape[2]
-    pos = jnp.where(tok_valid, seg_pos, L)
-    slot = jnp.where(tok_valid, seg_slot, 0)
-    return scale_cache.at[slot, :, pos].set(
-        jnp.swapaxes(scale_new, 1, 2), mode="drop")
-
-
 def attention_prefill_packed(cfg: ModelConfig, p: dict, x: jax.Array,
-                             cache: dict, seg_slot: jax.Array,
+                             cache: dict, layer, seg_slot: jax.Array,
                              seg_pos: jax.Array, seg_ids: jax.Array,
                              tok_valid: jax.Array, row_slot: jax.Array,
                              prefix_len: jax.Array, *, prefix_span: int):
@@ -423,37 +414,24 @@ def attention_prefill_packed(cfg: ModelConfig, p: dict, x: jax.Array,
     padded slice length the jit specializes on — the packed analogue of the
     unpacked path's static per-chunk ``offset``.
 
-    K/V scatter to (seg_slot, seg_pos); attention runs over the
-    concatenation [gathered prefix rows ; chunk KV] under the segment mask:
-    continuation tokens (segment 0) attend prefix positions < prefix_len
-    plus their own earlier chunk tokens, whole prompts attend only within
-    their segment. Padding rows produce garbage outputs — callers discard
-    them; their cache writes are dropped."""
+    K/V rows scatter to (layer, seg_slot, seg_pos) — the lane count B is
+    decoupled from the cache's slot count, and the planner covers every
+    prompt position once, so no two tokens share a cell; invalid tokens
+    are dropped. Attention runs over the concatenation [gathered prefix
+    rows ; chunk KV] under the segment mask: continuation tokens (segment
+    0) attend prefix positions < prefix_len plus their own earlier chunk
+    tokens, whole prompts attend only within their segment. Padding rows
+    produce garbage outputs — callers discard them."""
     B, C, _ = x.shape
+    S = cache["k"].shape[2]
     q, k_new, v_new = qkv_project(cfg, p, x, seg_pos)
-    new_cache = {}
-    if cfg.kv_dtype == "int8":
-        kq, ks = _quantize_kv(k_new)                        # scales (B, KH, C)
-        vq, vs = _quantize_kv(v_new)
-        k_cache, v_cache = write_kv_packed(cache["k"], cache["v"], kq, vq,
-                                           seg_slot, seg_pos, tok_valid)
-        k_sc = _write_scale_packed(cache["k_scale"], ks, seg_slot, seg_pos,
-                                   tok_valid)
-        v_sc = _write_scale_packed(cache["v_scale"], vs, seg_slot, seg_pos,
-                                   tok_valid)
-        new_cache.update(k_scale=k_sc, v_scale=v_sc)
-        # the chunk attends its own K/V through the same int8 round-trip the
-        # cache stores (numerical parity with later chunks reading the cache)
-        k_att_chunk = (kq.astype(jnp.bfloat16)
-                       * ks[..., None].astype(jnp.bfloat16))
-        v_att_chunk = (vq.astype(jnp.bfloat16)
-                       * vs[..., None].astype(jnp.bfloat16))
-    else:
-        k_cache, v_cache = write_kv_packed(cache["k"], cache["v"],
-                                           k_new, v_new,
-                                           seg_slot, seg_pos, tok_valid)
-        k_att_chunk, v_att_chunk = k_new, v_new
-    new_cache.update(k=k_cache, v=v_cache)
+    pos = jnp.where(tok_valid, seg_pos, S)                  # S drops
+    slot = jnp.where(tok_valid, seg_slot, 0)
+    # the chunk attends its own K/V through the same int8 round-trip the
+    # cache stores (numerical parity with later chunks reading the cache)
+    cache, (k_att_chunk, v_att_chunk) = _store_kv(
+        cfg, cache, lambda leaf, r: _write_rows(leaf, layer, slot, pos, r),
+        k_new, v_new)
 
     q_seg = jnp.where(tok_valid, seg_ids, -2)               # pad q matches 0 keys
     kv_seg_chunk = jnp.where(tok_valid, seg_ids, -1)
@@ -461,26 +439,14 @@ def attention_prefill_packed(cfg: ModelConfig, p: dict, x: jax.Array,
         # per-row prefix: the continuation segment's cache row, sliced to the
         # static span (>= every row's true prefix; the mask trims to
         # prefix_len so freshly scattered chunk tokens are never re-read)
-        span = min(prefix_span, k_cache.shape[2])
-        k_pref = jnp.take(jax.lax.slice_in_dim(k_cache, 0, span, axis=2),
-                          row_slot, axis=0)
-        v_pref = jnp.take(jax.lax.slice_in_dim(v_cache, 0, span, axis=2),
-                          row_slot, axis=0)
-        if cfg.kv_dtype == "int8":
-            k_psc = jnp.take(jax.lax.slice_in_dim(k_sc, 0, span, axis=2),
-                             row_slot, axis=0)
-            v_psc = jnp.take(jax.lax.slice_in_dim(v_sc, 0, span, axis=2),
-                             row_slot, axis=0)
-            k_pref = (k_pref.astype(jnp.bfloat16)
-                      * k_psc[..., None].astype(jnp.bfloat16))
-            v_pref = (v_pref.astype(jnp.bfloat16)
-                      * v_psc[..., None].astype(jnp.bfloat16))
+        span = min(prefix_span, S)
+        k_pref, v_pref = _layer_kv(cfg, cache, layer, span, slots=row_slot)
         pref_pos = jnp.broadcast_to(jnp.arange(span)[None], (B, span))
         pref_seg = jnp.where(pref_pos < prefix_len[:, None], 0, -1)
         k_att = jnp.concatenate(
-            [k_pref.astype(k_att_chunk.dtype), k_att_chunk], axis=2)
+            [k_pref.astype(k_att_chunk.dtype), k_att_chunk], axis=1)
         v_att = jnp.concatenate(
-            [v_pref.astype(v_att_chunk.dtype), v_att_chunk], axis=2)
+            [v_pref.astype(v_att_chunk.dtype), v_att_chunk], axis=1)
         kv_pos = jnp.concatenate([pref_pos, seg_pos], axis=1)
         kv_seg = jnp.concatenate([pref_seg, kv_seg_chunk], axis=1)
     else:
@@ -489,21 +455,19 @@ def attention_prefill_packed(cfg: ModelConfig, p: dict, x: jax.Array,
 
     seg_info = (seg_pos, q_seg, kv_pos, kv_seg)
     if cfg.use_pallas:
-        Skv = k_att.shape[2]
+        Skv = k_att.shape[1]
         bq, bkv = min(cfg.chunk_q, C), min(cfg.chunk_kv, Skv)
         if C % bq or Skv % bkv:
             raise ValueError(
                 f"use_pallas: packed chunk of {C} tokens over {Skv} keys "
                 f"does not tile with blocks ({bq}, {bkv})")
-        from repro.kernels.flash_attention import flash_attention
-        o = flash_attention(q, k_att, v_att, block_q=bq, block_kv=bkv,
-                            segment_info=seg_info)
+        o = _pallas_flash(q, k_att, v_att, block_q=bq, block_kv=bkv,
+                          segment_info=seg_info)
     else:
         o = flash_attention_xla(q, k_att, v_att, causal=True,
                                 chunk_q=cfg.chunk_q, chunk_kv=cfg.chunk_kv,
-                                segment_info=seg_info)
-    return out_project(p, o), new_cache
-
+                                segment_info=seg_info, kv_seq_axis=1)
+    return out_project(p, o), cache
 
 # --------------------------------------------------------------------------- #
 # Cross attention (Whisper decoder)
@@ -534,25 +498,35 @@ def encoder_kv(cfg: ModelConfig, p: dict, enc_out: jax.Array):
 # --------------------------------------------------------------------------- #
 # Decode (generation stage): one token against the KV cache
 # --------------------------------------------------------------------------- #
+def seq_sharded(num_kv_heads: int, mesh: Optional[Mesh]) -> bool:
+    """Layout B: the mesh's 'model' axis does not divide the KV heads, so
+    the cache is sharded over its sequence axis instead."""
+    if mesh is None or "model" not in mesh.axis_names:
+        return False
+    ext = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
+    return ext > 1 and num_kv_heads % ext != 0
+
+
 def _flash_decode_local(q, k, v, kv_valid):
     """Partial attention over a local KV shard with masking.
 
-    q: (B, KH, G, hd) f32; k/v: (B, KH, S_loc, hd); kv_valid: (B, S_loc) bool.
+    q: (B, KH, G, hd) f32; k/v: (B, S_loc, KH, hd); kv_valid: (B, S_loc) bool.
     Returns (o, m, l): partial output, running max, running sum.
     """
-    s = jnp.einsum("bkgh,bkch->bkgc", q, k.astype(jnp.float32))
+    s = jnp.einsum("bkgh,bckh->bkgc", q, k.astype(jnp.float32))
     s = jnp.where(kv_valid[:, None, None, :], s, NEG_INF)
     m = jnp.max(s, axis=-1)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, axis=-1)
-    o = jnp.einsum("bkgc,bkch->bkgh", p, v.astype(jnp.float32))
+    o = jnp.einsum("bkgc,bckh->bkgh", p, v.astype(jnp.float32))
     return o, m, l
 
 
 def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
                      v_cache: jax.Array, cur_len: jax.Array,
                      mesh: Optional[Mesh] = None) -> jax.Array:
-    """q: (B, H, 1, hd). k_cache/v_cache: (B, KH, S_max, hd), valid [0, cur_len).
+    """q: (B, H, 1, hd). k_cache/v_cache: (B, S_max, KH, hd), valid
+    [0, cur_len).
 
     Two layouts (DESIGN.md §6):
       A. kv_heads shards over 'model'  -> per-device GEMV, no combine.
@@ -560,17 +534,14 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
          shard_map flash-decode with a log-sum-exp combine (psum over model).
     """
     B, H, _, hd = q.shape
-    KH, S = k_cache.shape[1], k_cache.shape[2]
+    S, KH = k_cache.shape[1], k_cache.shape[2]
     G = H // KH
     scale = 1.0 / math.sqrt(hd)
     mesh = mesh or _current_mesh()
 
     qg = (q.reshape(B, KH, G, hd).astype(jnp.float32)) * scale
-    model_ext = 1
-    if mesh is not None and "model" in mesh.axis_names:
-        model_ext = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
 
-    if mesh is None or model_ext == 1 or KH % model_ext == 0:
+    if not seq_sharded(KH, mesh):
         # Layout A — heads sharded (or no TP): plain masked attention.
         valid = jnp.arange(S)[None, :] < cur_len[:, None]              # (B, S)
         o, m, l = _flash_decode_local(qg, k_cache, v_cache, valid)
@@ -578,10 +549,11 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
         return out.reshape(B, H, 1, hd).astype(q.dtype)
 
     # Layout B — sequence-sharded cache + cross-shard softmax combine.
-    info = MeshInfo(mesh)
+    model_ext = dict(zip(mesh.axis_names, mesh.devices.shape))["model"]
     batch_axes = logical_spec((B,), ("batch",), mesh)[0]
     cache_spec = logical_spec(k_cache.shape,
-                              ("batch", "kv_heads", "kv_seq", "head_dim"), mesh)
+                              ("batch", "kv_seq", "kv_heads", "head_dim"),
+                              mesh)
     q_spec = P(batch_axes, None, None, None)
     len_spec = P(batch_axes)
     s_loc = S // model_ext
@@ -608,35 +580,22 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k_cache: jax.Array,
     return out.reshape(B, H, 1, hd).astype(q.dtype)
 
 
-def update_kv_cache(k_cache: jax.Array, v_cache: jax.Array,
-                    k_new: jax.Array, v_new: jax.Array,
-                    cur_len: jax.Array, method: str = "onehot"):
-    """Insert one token's K/V at position cur_len (per batch row).
-
-    k_new/v_new: (B, KH, 1, hd).
-
-    method="onehot": mask-multiply over the whole cache. Trivially
-    SPMD-correct on a sequence-sharded cache, but touches O(cache) bytes —
-    this is the paper-faithful-but-naive baseline the §Perf loop iterates on.
-    method="scatter": O(1)-bytes scatter at (batch, position)."""
-    if method == "scatter":
-        B = k_cache.shape[0]
-        b_idx = jnp.arange(B)
-        k_cache = k_cache.at[b_idx, :, cur_len].set(
-            jnp.squeeze(k_new, 2), mode="drop")
-        v_cache = v_cache.at[b_idx, :, cur_len].set(
-            jnp.squeeze(v_new, 2), mode="drop")
-        return k_cache, v_cache
-    S = k_cache.shape[2]
-    onehot = (jnp.arange(S)[None, :] == cur_len[:, None])              # (B, S)
-    oh = onehot[:, None, :, None].astype(k_cache.dtype)
-    k_cache = k_cache * (1 - oh) + oh * k_new
-    v_cache = v_cache * (1 - oh) + oh * v_new
-    return k_cache, v_cache
+def _write_onehot(leaf: jax.Array, layer, cur_len: jax.Array,
+                  rows: jax.Array) -> jax.Array:
+    """Layout B's one-token write: select the new row at position cur_len
+    over the whole layer — elementwise, so each sequence shard applies it to
+    its own positions with no cross-shard traffic, at the cost of rewriting
+    the layer."""
+    B, S = leaf.shape[1], leaf.shape[2]
+    hit = (jnp.arange(S)[None, :] == cur_len[:, None]).reshape(
+        (B, S) + (1,) * (leaf.ndim - 3))
+    cur = jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+    new = jnp.where(hit, rows[:, None].astype(leaf.dtype), cur)
+    return jax.lax.dynamic_update_index_in_dim(leaf, new, layer, 0)
 
 
 def _quantize_kv(x: jax.Array):
-    """x: (B, KH, 1, hd) -> (int8, scale (B, KH, 1))."""
+    """x: (..., hd) -> (int8 (..., hd), scale (...)): one scale per row."""
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1)
     scale = jnp.maximum(amax / 127.0, 1e-8)
     q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale[..., None]),
@@ -645,36 +604,28 @@ def _quantize_kv(x: jax.Array):
 
 
 def attention_decode(cfg: ModelConfig, p: dict, x: jax.Array,
-                     cache: dict, cur_len: jax.Array,
+                     cache: dict, layer, cur_len: jax.Array,
                      mesh: Optional[Mesh] = None):
-    """One decode step. x: (B, 1, d). cache: {"k","v"} (B, KH, S_max, hd)
-    (+ "k_scale"/"v_scale" (B, KH, S_max) for the int8 cache).
+    """One decode step. x: (B, 1, d). ``cache`` holds this attention
+    position's stacked leaves, "k"/"v" (layers, B, S_max, KH, hd) (+ the
+    int8 cache's "k_scale"/"v_scale" (layers, B, S_max, KH)); ``layer``
+    indexes them. The token's K/V row is written at (layer, b, cur_len[b])
+    first — one row per slot, or the onehot select of a sequence-sharded
+    cache — and the layer is then read back to attend over cur_len + 1.
     Returns (out (B,1,d), new_cache)."""
     positions = cur_len[:, None]                                       # (B, 1)
     q, k_new, v_new = qkv_project(cfg, p, x, positions)
-    new_cache = {}
-    if cfg.kv_dtype == "int8":
-        # quantize the inserted token; dequantize blocks at attention time
-        # (halves decode HBM traffic — §Perf iteration B2)
-        kq, ks = _quantize_kv(k_new)
-        vq, vs = _quantize_kv(v_new)
-        k_cache, v_cache = update_kv_cache(cache["k"], cache["v"], kq, vq,
-                                           cur_len, method=cfg.kv_update)
-        k_sc, v_sc = update_kv_cache(
-            cache["k_scale"][..., None], cache["v_scale"][..., None],
-            ks[..., None], vs[..., None], cur_len, method=cfg.kv_update)
-        k_sc, v_sc = k_sc[..., 0], v_sc[..., 0]
-        new_cache.update(k_scale=k_sc, v_scale=v_sc)
-        k_att = (k_cache.astype(jnp.bfloat16)
-                 * k_sc[..., None].astype(jnp.bfloat16))
-        v_att = (v_cache.astype(jnp.bfloat16)
-                 * v_sc[..., None].astype(jnp.bfloat16))
+    mesh = mesh or _current_mesh()
+    B = x.shape[0]
+    if seq_sharded(cfg.num_kv_heads, mesh):
+        def put(leaf, rows):
+            return _write_onehot(leaf, layer, cur_len, rows)
     else:
-        k_cache, v_cache = update_kv_cache(cache["k"], cache["v"],
-                                           k_new, v_new,
-                                           cur_len, method=cfg.kv_update)
-        k_att, v_att = k_cache, v_cache
+        def put(leaf, rows):
+            return _write_rows(leaf, layer, jnp.arange(B), cur_len, rows)
+    # (quantized on insert for the int8 cache, dequantized at attention
+    # time: halves decode HBM traffic — §Perf iteration B2)
+    cache, _ = _store_kv(cfg, cache, put, k_new[:, 0], v_new[:, 0])
+    k_att, v_att = _layer_kv(cfg, cache, layer)
     o = decode_attention(cfg, q, k_att, v_att, cur_len + 1, mesh)
-    out = out_project(p, o)
-    new_cache.update(k=k_cache, v=v_cache)
-    return out, new_cache
+    return out_project(p, o), cache

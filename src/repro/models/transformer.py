@@ -106,7 +106,10 @@ def param_defs(cfg: ModelConfig) -> dict:
 
 def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     """Decode-time state as a ParamDef tree (zeros init, logical axes drive
-    the sharded layout — kv_seq falls back to 'model' for narrow GQA)."""
+    the sharded layout — kv_seq falls back to 'model' for narrow GQA).
+    Every leaf stacks the layers first and the slots second; the attention
+    K/V is position-major, (layers, slots, max_len, KH, hd), so a token's
+    K/V is one contiguous row that a step program writes in place."""
     p = superblock_period(cfg)
     n_super = cfg.num_layers // p
     kh, hd = cfg.num_kv_heads, cfg.head_dim
@@ -114,14 +117,14 @@ def cache_defs(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     for j, (mixer, _ffn) in enumerate(_position_kinds(cfg)):
         c: Dict[str, Any] = {}
         if mixer == "attn":
-            shape = (n_super, batch, kh, max_len, hd)
-            axes = ("layers", "batch", "kv_heads", "kv_seq", "head_dim")
+            shape = (n_super, batch, max_len, kh, hd)
+            axes = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
             kv_dt = "int8" if cfg.kv_dtype == "int8" else cfg.dtype
             c["k"] = ParamDef(shape, axes, "zeros", dtype=kv_dt)
             c["v"] = ParamDef(shape, axes, "zeros", dtype=kv_dt)
             if cfg.kv_dtype == "int8":
-                s_shape = (n_super, batch, kh, max_len)
-                s_axes = ("layers", "batch", "kv_heads", "kv_seq")
+                s_shape = (n_super, batch, max_len, kh)
+                s_axes = ("layers", "batch", "kv_seq", "kv_heads")
                 c["k_scale"] = ParamDef(s_shape, s_axes, "zeros",
                                         dtype="float32")
                 c["v_scale"] = ParamDef(s_shape, s_axes, "zeros",
@@ -195,22 +198,32 @@ def _apply_block_full(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
     return constrain(x, ("batch", "seq", "d_model")), aux
 
 
+def _at(leaf: jax.Array, layer) -> jax.Array:
+    return jax.lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+
+
+def _put(leaf: jax.Array, layer, value: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_update_index_in_dim(
+        leaf, value.astype(leaf.dtype), layer, 0)
+
+
 def _apply_block_decode(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
-                        x: jax.Array, cache: dict, cur_len: jax.Array):
-    """One-token block. x: (B,1,d). Returns (x, new_cache)."""
+                        x: jax.Array, cache: dict, layer,
+                        cur_len: jax.Array):
+    """One-token block. x: (B,1,d); ``cache`` is this position's stacked
+    state and ``layer`` indexes it. Returns (x, new_cache)."""
     mixer, ffn = kind
     new_cache = dict(cache)
     if mixer == "attn":
         h = L.apply_norm(cfg, p["norm1"], x)
-        kv_in = {k: cache[k] for k in ("k", "v", "k_scale", "v_scale")
-                 if k in cache}
-        y, kv = A.attention_decode(cfg, p["attn"], h, kv_in, cur_len)
-        new_cache.update(kv)
+        y, new_cache = A.attention_decode(cfg, p["attn"], h, new_cache,
+                                          layer, cur_len)
         x = x + y
         if "ck" in cache:
             h = L.apply_norm(cfg, p["norm_cross"], x)
             x = x + A.cross_attention(cfg, p["cross"], h,
-                                      (cache["ck"], cache["cv"]))
+                                      (_at(cache["ck"], layer),
+                                       _at(cache["cv"], layer)))
         h = L.apply_norm(cfg, p["norm2"], x)
         if ffn == "moe":
             y, _ = M.apply_moe(cfg, p["ffn"], h)
@@ -220,8 +233,10 @@ def _apply_block_decode(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
     elif mixer == "mamba":
         h = L.apply_norm(cfg, p["norm1"], x)
         y, st = S.mamba_mix(cfg, p["mamba"], h,
-                            state={"conv": cache["conv"], "ssm": cache["ssm"]})
-        new_cache["conv"], new_cache["ssm"] = st["conv"], st["ssm"]
+                            state={k: _at(cache[k], layer)
+                                   for k in ("conv", "ssm")})
+        for k in ("conv", "ssm"):
+            new_cache[k] = _put(cache[k], layer, st[k])
         x = x + y
         h = L.apply_norm(cfg, p["norm2"], x)
         if ffn == "moe":
@@ -232,14 +247,16 @@ def _apply_block_decode(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
     else:  # rwkv
         h = L.apply_norm(cfg, p["norm1"], x)
         y, st = S.rwkv_time_mix(cfg, p["rwkv"], h,
-                                state={"shift_tm": cache["shift_tm"],
-                                       "wkv": cache["wkv"]})
-        new_cache["shift_tm"], new_cache["wkv"] = st["shift_tm"], st["wkv"]
+                                state={k: _at(cache[k], layer)
+                                       for k in ("shift_tm", "wkv")})
+        for k in ("shift_tm", "wkv"):
+            new_cache[k] = _put(cache[k], layer, st[k])
         x = x + y
         h = L.apply_norm(cfg, p["norm2"], x)
-        y, st = S.rwkv_channel_mix(cfg, p["rwkv"], h,
-                                   state={"shift_cm": cache["shift_cm"]})
-        new_cache["shift_cm"] = st["shift_cm"]
+        y, st = S.rwkv_channel_mix(
+            cfg, p["rwkv"], h,
+            state={"shift_cm": _at(cache["shift_cm"], layer)})
+        new_cache["shift_cm"] = _put(cache["shift_cm"], layer, st["shift_cm"])
         x = x + y
     return x, new_cache
 
@@ -274,6 +291,29 @@ def _loop_blocks(cfg: ModelConfig, body, carry, xs):
     else:
         ys = None
     return carry, ys
+
+
+def _serve_blocks(cfg: ModelConfig, apply, x, cache: dict, blocks: dict):
+    """The serving layer loop: ``apply(kind, p, x, cache_j, layer)`` ->
+    (x, cache_j) for each superblock position j of each stacked block. The
+    whole cache is loop CARRY (not scanned xs -> ys, which would rebuild a
+    fresh stacked copy), so each layer writes only its own rows into the
+    one buffer — in place when the caller donates it."""
+    kinds = _position_kinds(cfg)
+    n = jax.tree.leaves(blocks)[0].shape[0]
+
+    def body(carry, xs):
+        x, cache = carry
+        blk, layer = xs
+        cache = dict(cache)
+        for j, kind in enumerate(kinds):
+            x, cache[f"pos{j}"] = apply(kind, blk[f"pos{j}"], x,
+                                        cache[f"pos{j}"], layer)
+        return (x, cache), None
+
+    (x, cache), _ = _loop_blocks(cfg, body, (x, cache),
+                                 (blocks, jnp.arange(n)))
+    return x, cache
 
 
 def encode(cfg: ModelConfig, params: dict, frame_embeds: jax.Array) -> jax.Array:
@@ -361,18 +401,11 @@ def decode_step(cfg: ModelConfig, params: dict, tokens: jax.Array,
     """tokens: (B, 1) int32; cur_len: (B,) current context lengths.
     Returns (logits (B, V), new_cache)."""
     x = L.embed_tokens(params["embed"], tokens, cfg.d_model)
-    kinds = _position_kinds(cfg)
 
-    def body(x, xs):
-        blk, cache_slice = xs
-        new_slice = {}
-        for j, kind in enumerate(kinds):
-            x, nc = _apply_block_decode(cfg, kind, blk[f"pos{j}"], x,
-                                        cache_slice[f"pos{j}"], cur_len)
-            new_slice[f"pos{j}"] = nc
-        return x, new_slice
+    def apply(kind, p, x, c, layer):
+        return _apply_block_decode(cfg, kind, p, x, c, layer, cur_len)
 
-    x, new_cache = _loop_blocks(cfg, body, x, (params["blocks"], cache))
+    x, new_cache = _serve_blocks(cfg, apply, x, cache, params["blocks"])
     x = L.apply_norm(cfg, params["final_norm"], x)
     logits = L.lm_logits(params["embed"], x, cfg.tie_embeddings)
     return logits[:, 0, :], new_cache
@@ -507,20 +540,16 @@ def supports_batched_prefill(cfg: ModelConfig) -> bool:
 
 
 def _apply_block_prefill(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
-                         x: jax.Array, cache: dict, tok_valid: jax.Array,
-                         offset: int):
+                         x: jax.Array, cache: dict, layer,
+                         tok_valid: jax.Array, offset: int):
     """Chunk-of-prompt block. x: (B, C, d). Returns (x, new_cache)."""
     mixer, ffn = kind
     if mixer != "attn":
         raise NotImplementedError(
             "batched prefill covers attention mixers only")
-    new_cache = dict(cache)
     h = L.apply_norm(cfg, p["norm1"], x)
-    kv_in = {k: cache[k] for k in ("k", "v", "k_scale", "v_scale")
-             if k in cache}
-    y, kv = A.attention_prefill_cached(cfg, p["attn"], h, kv_in,
-                                       tok_valid, offset)
-    new_cache.update(kv)
+    y, new_cache = A.attention_prefill_cached(cfg, p["attn"], h, cache,
+                                              layer, tok_valid, offset)
     x = x + y
     h = L.apply_norm(cfg, p["norm2"], x)
     if ffn == "moe":
@@ -542,24 +571,16 @@ def prefill_chunk(cfg: ModelConfig, params: dict, tokens: jax.Array,
     prefilling an S-token prompt costs ceil(S/C) dispatches instead of S
     sequential decode steps."""
     x = L.embed_tokens(params["embed"], tokens, cfg.d_model)
-    kinds = _position_kinds(cfg)
 
-    def body(x, xs):
-        blk, cache_slice = xs
-        new_slice = {}
-        for j, kind in enumerate(kinds):
-            x, nc = _apply_block_prefill(cfg, kind, blk[f"pos{j}"], x,
-                                         cache_slice[f"pos{j}"],
-                                         tok_valid, offset)
-            new_slice[f"pos{j}"] = nc
-        return x, new_slice
+    def apply(kind, p, x, c, layer):
+        return _apply_block_prefill(cfg, kind, p, x, c, layer, tok_valid,
+                                    offset)
 
-    _, new_cache = _loop_blocks(cfg, body, x, (params["blocks"], cache))
-    return new_cache
+    return _serve_blocks(cfg, apply, x, cache, params["blocks"])[1]
 
 
 def _apply_block_prefill_packed(cfg: ModelConfig, kind: Tuple[str, str],
-                                p: dict, x: jax.Array, cache: dict,
+                                p: dict, x: jax.Array, cache: dict, layer,
                                 seg_slot, seg_pos, seg_ids, tok_valid,
                                 row_slot, prefix_len, prefix_span: int):
     """Packed chunk-of-prompts block. x: (B, C, d). Returns (x, new_cache)."""
@@ -567,15 +588,10 @@ def _apply_block_prefill_packed(cfg: ModelConfig, kind: Tuple[str, str],
     if mixer != "attn":
         raise NotImplementedError(
             "packed prefill covers attention mixers only")
-    new_cache = dict(cache)
     h = L.apply_norm(cfg, p["norm1"], x)
-    kv_in = {k: cache[k] for k in ("k", "v", "k_scale", "v_scale")
-             if k in cache}
-    y, kv = A.attention_prefill_packed(cfg, p["attn"], h, kv_in,
-                                       seg_slot, seg_pos, seg_ids,
-                                       tok_valid, row_slot, prefix_len,
-                                       prefix_span=prefix_span)
-    new_cache.update(kv)
+    y, new_cache = A.attention_prefill_packed(
+        cfg, p["attn"], h, cache, layer, seg_slot, seg_pos, seg_ids,
+        tok_valid, row_slot, prefix_len, prefix_span=prefix_span)
     x = x + y
     h = L.apply_norm(cfg, p["norm2"], x)
     if ffn == "moe":
@@ -600,21 +616,13 @@ def prefill_chunk_packed(cfg: ModelConfig, params: dict, tokens: jax.Array,
     Returns the new cache (packed prefill emits no logits, like
     ``prefill_chunk``)."""
     x = L.embed_tokens(params["embed"], tokens, cfg.d_model)
-    kinds = _position_kinds(cfg)
 
-    def body(x, xs):
-        blk, cache_slice = xs
-        new_slice = {}
-        for j, kind in enumerate(kinds):
-            x, nc = _apply_block_prefill_packed(
-                cfg, kind, blk[f"pos{j}"], x, cache_slice[f"pos{j}"],
-                seg_slot, seg_pos, seg_ids, tok_valid, row_slot,
-                prefix_len, prefix_span)
-            new_slice[f"pos{j}"] = nc
-        return x, new_slice
+    def apply(kind, p, x, c, layer):
+        return _apply_block_prefill_packed(
+            cfg, kind, p, x, c, layer, seg_slot, seg_pos, seg_ids,
+            tok_valid, row_slot, prefix_len, prefix_span)
 
-    _, new_cache = _loop_blocks(cfg, body, x, (params["blocks"], cache))
-    return new_cache
+    return _serve_blocks(cfg, apply, x, cache, params["blocks"])[1]
 
 
 # --------------------------------------------------------------------------- #

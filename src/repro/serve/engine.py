@@ -118,15 +118,19 @@ class Request:
 # Jitted entry points are cached at module level keyed by the (frozen,
 # hashable) ModelConfig: every ServeEngine for the same config shares one
 # compiled decode step and one compiled prefill per chunk index, instead of
-# recompiling per engine instance.
+# recompiling per engine instance. Every program that takes the KV cache
+# donates it (``donate_argnums`` names the cache's position): the program
+# writes its rows into the same buffer, the engine rebinds ``self.cache`` to
+# the result, and the old handle is dead — so no dispatch copies the cache.
 @functools.lru_cache(maxsize=None)
 def _jit_decode(cfg: ModelConfig):
-    return jax.jit(functools.partial(T.decode_step, cfg))
+    return jax.jit(functools.partial(T.decode_step, cfg), donate_argnums=2)
 
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill(cfg: ModelConfig, offset: int):
-    return jax.jit(functools.partial(T.prefill_chunk, cfg, offset=offset))
+    return jax.jit(functools.partial(T.prefill_chunk, cfg, offset=offset),
+                   donate_argnums=2)
 
 
 @functools.lru_cache(maxsize=None)
@@ -137,7 +141,8 @@ def _jit_prefill_packed(cfg: ModelConfig, prefix_span: int):
     serve compiles at most max_slots * max_len/chunk packed variants — the
     same order as the unpacked path's per-chunk-offset jits."""
     return jax.jit(functools.partial(T.prefill_chunk_packed, cfg,
-                                     prefix_span=prefix_span))
+                                     prefix_span=prefix_span),
+                   donate_argnums=2)
 
 
 @functools.lru_cache(maxsize=None)
@@ -147,7 +152,7 @@ def _jit_decode_sample(cfg: ModelConfig, temperature: float,
     length/termination update in ONE dispatch, one (3, B) fetch."""
     return jax.jit(functools.partial(
         T.decode_and_sample, cfg, temperature=temperature,
-        eos_token=eos_token, max_len=max_len))
+        eos_token=eos_token, max_len=max_len), donate_argnums=1)
 
 
 @functools.lru_cache(maxsize=None)
@@ -158,7 +163,7 @@ def _jit_decode_superstep(cfg: ModelConfig, temperature: float,
     amortization lever for launch-overhead-bound decode."""
     return jax.jit(functools.partial(
         T.decode_superstep, cfg, k=k, temperature=temperature,
-        eos_token=eos_token, max_len=max_len))
+        eos_token=eos_token, max_len=max_len), donate_argnums=1)
 
 
 @functools.lru_cache(maxsize=None)
@@ -168,7 +173,7 @@ def _jit_fused_step(cfg: ModelConfig, temperature: float,
     resident batch's decode + the chunk's prefill in one program."""
     return jax.jit(functools.partial(
         T.fused_step, cfg, offset=offset, temperature=temperature,
-        eos_token=eos_token, max_len=max_len))
+        eos_token=eos_token, max_len=max_len), donate_argnums=1)
 
 
 @functools.lru_cache(maxsize=None)
@@ -179,7 +184,23 @@ def _jit_fused_step_packed(cfg: ModelConfig, temperature: float,
     same specialization scheme as ``_jit_prefill_packed``)."""
     return jax.jit(functools.partial(
         T.fused_step_packed, cfg, prefix_span=prefix_span,
-        temperature=temperature, eos_token=eos_token, max_len=max_len))
+        temperature=temperature, eos_token=eos_token, max_len=max_len),
+        donate_argnums=1)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def reset_slots(cache: dict, slots: jax.Array) -> dict:
+    """Zero the admitted slots' cache rows, in place. ``slots`` is a
+    fixed-shape (max_slots,) int32 array, the admitted slots first and the
+    rest padded with max_slots, so one program serves every wave size and
+    only the admitted rows are written (a scatter would visit the padding
+    too). A named function, not a partial: its program is
+    ``jit_reset_slots``, never taken for a step program."""
+    def zero(i, cache):
+        return jax.tree.map(lambda leaf: jax.lax.dynamic_update_index_in_dim(
+            leaf, jnp.zeros(leaf.shape[:1] + leaf.shape[2:], leaf.dtype),
+            slots[i], 1), cache)
+    return jax.lax.fori_loop(0, jnp.sum(slots < slots.shape[0]), zero, cache)
 
 
 @dataclass(frozen=True)
@@ -323,11 +344,11 @@ class ServeEngine:
                                "export_syncs": 0, "restores": 0,
                                "restored_tokens": 0, "restore_bytes": 0}
         # per-slot row slices rely on every cache leaf carrying the slot
-        # axis at position 1 and the kv_seq axis at position 3 (attention
+        # axis at position 1 and the kv_seq axis at position 2 (attention
         # K/V + int8 scales do; SSM/RWKV/enc-dec state trees do not)
         self._snapshot_ok = self._batched_ok and all(
             getattr(leaf, "ndim", 0) in (4, 5)
-            and leaf.shape[1] == B and leaf.shape[3] == L
+            and leaf.shape[1] == B and leaf.shape[2] == L
             for leaf in jax.tree.leaves(self.cache))
         self.step_idx = 0             # engine step counter (trace timeline)
         self.wave_count = 0           # admission waves (trace sub-batch ids)
@@ -437,7 +458,7 @@ class ServeEngine:
     def snapshot_supported(self) -> bool:
         """KV export/import works when every cache leaf is an attention
         K/V (or int8 scale) tensor with the slot axis at position 1 and the
-        kv_seq axis at position 3 — the per-slot row slice both directions
+        kv_seq axis at position 2 — the per-slot row slice both directions
         rely on. SSM/RWKV/enc-dec state trees (and the sequential prefill
         fallback) are not snapshotable."""
         return self._snapshot_ok
@@ -449,7 +470,7 @@ class ServeEngine:
         (the ``SnapshotStore``'s high-water view for this node); a slot
         whose prefix hasn't grown exports nothing. Each entry carries the
         new cache rows [base, prefix_len) per leaf (slot axis removed; the
-        kv_seq axis becomes axis 2) plus the host-side request state a
+        kv_seq axis becomes axis 1) plus the host-side request state a
         survivor needs: generated tokens, remaining budget, last token and
         the engine rng — metadata only, never imported into a survivor.
         ``prefix_len`` is host-derived (``len(prompt)-1+len(generated)`` ==
@@ -469,7 +490,7 @@ class ServeEngine:
             base = int(since.get(req.gid, 0))
             if P <= base:
                 continue
-            idx = (slice(None), slot, slice(None), slice(base, P))
+            idx = (slice(None), slot, slice(base, P))
             rows = {k: np.asarray(leaf[idx]) for k, leaf in flat.items()}
             nbytes = int(sum(a.nbytes for a in rows.values()))
             self.snapshot_stats["exports"] += 1
@@ -500,7 +521,7 @@ class ServeEngine:
         P = int(snapshot["prefix_len"])
         rows = snapshot["cache"]
         flat = _flatten_cache(self.cache)
-        idx = (slice(None), slot, slice(None), slice(0, P))
+        idx = (slice(None), slot, slice(0, P))
         out = {}
         for key, leaf in flat.items():
             out[key] = leaf.at[idx].set(jnp.asarray(rows[key]))
@@ -589,11 +610,14 @@ class ServeEngine:
                 admitted.append((free.pop(0), self.queue.pop(0)))
             for r in self.queue:
                 r.deferred += 1
-            sl = jnp.asarray(np.array([s for s, _ in admitted]))
-            # one masked reset for the whole admission batch (cache rows +
-            # lens)
-            self.cache = jax.tree.map(lambda leaf: leaf.at[:, sl].set(0),
-                                      self.cache)
+            slots = [s for s, _ in admitted]
+            sl = jnp.asarray(np.array(slots))
+            # one in-place reset of the admitted slots' cache rows, one
+            # program whatever the wave's size
+            padded = np.full(self.scfg.max_slots, self.scfg.max_slots,
+                             np.int32)
+            padded[:len(slots)] = slots
+            self.cache = reset_slots(self.cache, jnp.asarray(padded))
             # The fused decode step writes K/V at lens[slot] for EVERY slot
             # (inactive ones included) as a dispatch side effect. While a
             # slot is mid-prefill under an interleaving policy, co-scheduled

@@ -127,14 +127,20 @@ def logical_spec(shape: Sequence[int], logical_axes: Sequence[Optional[str]],
                  mesh: Mesh, rules: Optional[dict] = None) -> P:
     """PartitionSpec for `shape` whose dims carry `logical_axes` names.
 
-    Dims are resolved left-to-right; a mesh axis claimed by an earlier dim is
-    unavailable to later dims (e.g. a decode KV cache (batch, kv_heads,
-    kv_seq, hd): batch claims 'data'; kv_heads claims 'model' when divisible,
-    otherwise kv_seq claims 'model' — the GQA-aware fallback)."""
+    Dims are resolved left-to-right, except that kv_seq goes last; a mesh
+    axis claimed by an earlier dim is unavailable to later dims (e.g. a
+    decode KV cache (batch, kv_seq, kv_heads, hd): batch claims 'data';
+    kv_heads claims 'model' when divisible, otherwise kv_seq claims 'model'
+    — the GQA-aware fallback, whatever the order of the two dims)."""
     assert len(shape) == len(logical_axes), (shape, logical_axes)
     info = MeshInfo(mesh)
     used: set = set()
-    return P(*[_resolve_dim(s, a, info, used, rules) for s, a in zip(shape, logical_axes)])
+    order = sorted(range(len(shape)),
+                   key=lambda i: logical_axes[i] == "kv_seq")
+    spec = [None] * len(shape)
+    for i in order:
+        spec[i] = _resolve_dim(shape[i], logical_axes[i], info, used, rules)
+    return P(*spec)
 
 
 def logical_sharding(shape, logical_axes, mesh, rules=None) -> NamedSharding:

@@ -49,12 +49,10 @@ def test_smoke_forward_and_train_step(arch):
         assert bool(jnp.isfinite(leaf.astype(jnp.float32)).all())
 
 
-@pytest.mark.parametrize("arch", ASSIGNED)
-def test_decode_matches_prefill(arch):
-    """Teacher-forced decode must reproduce full-sequence logits exactly
-    (cache correctness for every mixer family)."""
-    cfg = get_arch(arch).reduced()
-    cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # no MoE drops
+def _decode_vs_forward(cfg, atol=2e-2):
+    """Teacher-forced decode through the donated, in-place ``decode_step``
+    against ``forward_full``; returns (full, decoded) logits, or None where
+    the family's decode is covered elsewhere."""
     params = init_params(T.param_defs(cfg), KEY)
     B, S = 2, 8
     batch = batch_for(cfg, B, S)
@@ -72,22 +70,46 @@ def test_decode_matches_prefill(arch):
                                           frame_embeds=frames)
         np.testing.assert_allclose(
             np.asarray(full[:, -1].astype(jnp.float32)),
-            np.asarray(last), rtol=2e-2, atol=2e-2)
-        return
+            np.asarray(last), rtol=2e-2, atol=atol)
+        return None
 
     full, _ = T.forward_full(cfg, params, tokens)
     cache = init_params(T.cache_defs(cfg, B, 16), KEY)
     lens = jnp.zeros((B,), jnp.int32)
-    step = jax.jit(lambda p, t, c, l: T.decode_step(cfg, p, t, c, l))
+    step = jax.jit(lambda p, t, c, l: T.decode_step(cfg, p, t, c, l),
+                   donate_argnums=2)
     outs = []
     for t in range(tokens.shape[1]):
         lg, cache = step(params, tokens[:, t][:, None], cache, lens)
         lens = lens + 1
         outs.append(lg)
-    dec = jnp.stack(outs, 1)
-    np.testing.assert_allclose(
-        np.asarray(full.astype(jnp.float32)),
-        np.asarray(dec.astype(jnp.float32)), rtol=2e-2, atol=2e-2)
+    return (np.asarray(full.astype(jnp.float32)),
+            np.asarray(jnp.stack(outs, 1).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("arch", ASSIGNED)
+def test_decode_matches_prefill(arch):
+    """Teacher-forced decode must reproduce full-sequence logits exactly
+    (cache correctness for every mixer family)."""
+    cfg = get_arch(arch).reduced()
+    cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # no MoE drops
+    out = _decode_vs_forward(cfg)
+    if out is not None:
+        np.testing.assert_allclose(*out, rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("arch", ASSIGNED)
+def test_unrolled_decode_matches_prefill(arch):
+    """The unrolled layer loop (scan_layers=False) carries and writes the
+    cache the same way as the scanned one (tolerance as in the
+    scan-vs-unrolled forward test below: the unrolled lowering fuses
+    differently, so bf16 roundings differ by an ulp)."""
+    cfg = get_arch(arch).reduced()
+    cfg = dataclasses.replace(cfg, capacity_factor=8.0, scan_layers=False,
+                              remat="none")
+    out = _decode_vs_forward(cfg, atol=0.1)
+    if out is not None:
+        np.testing.assert_allclose(*out, rtol=2e-2, atol=0.1)
 
 
 @pytest.mark.parametrize("arch", ASSIGNED)
@@ -153,11 +175,12 @@ def test_int8_kv_cache_decode_close_to_bf16():
     params = init_params(T.param_defs(cfg), KEY)
     tokens = jax.random.randint(KEY, (2, 8), 0, cfg.vocab_size)
     full, _ = T.forward_full(cfg, params, tokens)
-    c2 = dataclasses.replace(cfg, kv_dtype="int8", kv_update="scatter")
+    c2 = dataclasses.replace(cfg, kv_dtype="int8")
     cache = init_params(T.cache_defs(c2, 2, 16), KEY)
     assert cache["pos0"]["k"].dtype == jnp.int8
     lens = jnp.zeros((2,), jnp.int32)
-    step = jax.jit(lambda p, t, c, l: T.decode_step(c2, p, t, c, l))
+    step = jax.jit(lambda p, t, c, l: T.decode_step(c2, p, t, c, l),
+                   donate_argnums=2)
     outs = []
     for t in range(8):
         lg, cache = step(params, tokens[:, t][:, None], cache, lens)
@@ -167,3 +190,18 @@ def test_int8_kv_cache_decode_close_to_bf16():
     ref = full.astype(jnp.float32)
     rel = float(jnp.max(jnp.abs(ref - dec)) / jnp.max(jnp.abs(ref)))
     assert rel < 0.05, rel
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "qwen3-moe-30b-a3b",
+                                  "jamba-v0.1-52b", "whisper-medium"])
+def test_int8_decode_tracks_forward(arch):
+    """The int8 cache through the in-place decode of each attention-holding
+    family (dense, MoE, hybrid, encoder-decoder) tracks the bf16 forward
+    within quantization error, as in the dense case above."""
+    cfg = get_arch(arch).reduced()
+    cfg = dataclasses.replace(cfg, capacity_factor=8.0, kv_dtype="int8")
+    out = _decode_vs_forward(cfg, atol=0.05)
+    if out is not None:
+        full, dec = out
+        rel = float(np.max(np.abs(full - dec)) / np.max(np.abs(full)))
+        assert rel < 0.05, rel

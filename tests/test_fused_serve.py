@@ -106,6 +106,52 @@ def test_fused_superstep_packed_matches(fused_superstep_serve, baseline):
     assert fused_superstep_serve[2] == baseline[2]
 
 
+@pytest.mark.parametrize("policy", ("serial", "pim_aware"))
+def test_packed_superstep_matches_serial(setup, arrivals, baseline, policy):
+    """pack + fuse + superstep 4 under the policies the mixed serve above
+    does not run: every donated step program leaves the reference tokens."""
+    cfg, params = setup
+    _eng, _rec, res = _serve(cfg, params, policy, arrivals, pack=True,
+                             fuse=True, superstep=4)
+    assert res == baseline[2]
+
+
+def _backend_donates() -> bool:
+    x = jnp.zeros((4,))
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    return x.is_deleted()
+
+
+@pytest.mark.parametrize("arch,policy,kw", [
+    ("llama3.2-1b", "serial", {}),
+    ("llama3.2-1b", "serial", {"pack": True, "superstep": 4}),
+    ("llama3.2-1b", "interleaved", {"fuse": True}),
+    ("llama3.2-1b", "interleaved", {"pack": True, "fuse": True}),
+    ("rwkv6-7b", "serial", {}),            # the sequential prefill path
+])
+def test_engine_steps_donate_the_cache(arch, policy, kw):
+    """Every step that dispatches hands the cache it held to the program:
+    the arrays the engine held before the step are deleted after it, so no
+    step kept a second copy of the cache alive."""
+    if not _backend_donates():
+        pytest.skip("this backend ignores buffer donation")
+    cfg = get_arch(arch).reduced()
+    params = init_params(T.param_defs(cfg), KEY)
+    eng = ServeEngine(cfg, params, _scfg(policy, **kw))
+    rng = np.random.default_rng(5)
+    for n in (9, 20, 3):
+        eng.add_request(rng.integers(0, cfg.vocab_size, n), 6)
+    steps = 0
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        held = jax.tree.leaves(eng.cache)
+        counts = dict(eng.dispatch_counts)
+        eng.step()
+        if eng.dispatch_counts != counts:
+            assert all(leaf.is_deleted() for leaf in held), steps
+            steps += 1
+    assert steps > 0
+
+
 def test_superstep_rng_freezes_on_dead_rounds(setup):
     """The scan must not consume rng splits on rounds with no live lane
     (the per-step engine would never have dispatched them): after the only

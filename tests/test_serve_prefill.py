@@ -115,8 +115,8 @@ def test_prefill_chunk_cache_matches_sequential_decode(setup):
         for k in cache_s[pos]:
             a = np.asarray(cache_s[pos][k], np.float32)
             b = np.asarray(cache_b[pos][k], np.float32)
-            m = valid_pos[None, :, None, :, None] if a.ndim == 5 \
-                else valid_pos[None, :, None, :]
+            m = valid_pos[None, :, :, None, None] if a.ndim == 5 \
+                else valid_pos[None, :, :, None]
             np.testing.assert_allclose(a * m, b * m, rtol=2e-2, atol=2e-2,
                                        err_msg=f"{pos}/{k}")
 

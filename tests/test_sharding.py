@@ -87,3 +87,70 @@ def test_fsdp_weights_shard_both_axes():
     m = _FakeMesh({"data": 16, "model": 16})
     spec = logical_spec((384, 7168, 2048), ("experts", "fsdp", "d_ff"), m)
     assert spec[0] == "model" and spec[1] == "data"
+
+
+def test_cache_kv_seq_claims_model_after_kv_heads():
+    """The cache stores kv_seq BEFORE kv_heads (position-major); kv_seq
+    still takes 'model' only when kv_heads could not."""
+    m = _FakeMesh({"data": 16, "model": 16})
+    axes = ("layers", "batch", "kv_seq", "kv_heads", "head_dim")
+    spec = logical_spec((4, 128, 32768, 16, 64), axes, m)
+    assert spec[2] is None and spec[3] == "model"
+    spec = logical_spec((4, 128, 32768, 8, 64), axes, m)
+    assert spec[2] == "model" and spec[3] is None
+
+
+_MESH_DECODE = """
+import dataclasses
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
+from repro.models import transformer as T
+from repro.models.attention import seq_sharded
+from repro.models.params import init_params
+
+base = get_arch("llama3.2-1b").reduced()
+mesh = make_mesh((1, 4), ("data", "model"))
+for kh, layout_b in ((4, False), (2, True)):
+    cfg = dataclasses.replace(base, num_kv_heads=kh)
+    assert seq_sharded(kh, mesh) == layout_b
+    params = init_params(T.param_defs(cfg), jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0,
+                              cfg.vocab_size)
+    outs = []
+    for use_mesh in (False, True):
+        step = jax.jit(lambda p, t, c, l: T.decode_step(cfg, p, t, c, l),
+                       donate_argnums=2)
+        cache = init_params(T.cache_defs(cfg, 2, 16), jax.random.PRNGKey(0))
+        lens = jnp.zeros((2,), jnp.int32)
+        got = []
+        for t in range(toks.shape[1]):
+            with (mesh if use_mesh else jax.sharding.Mesh(
+                    np.array(jax.devices()[:1]).reshape(1, 1),
+                    ("data", "model"))):
+                lg, cache = step(params, toks[:, t:t + 1], cache, lens)
+            lens = lens + 1
+            got.append(np.asarray(lg, np.float32))
+        outs.append(np.stack(got, 1))
+    np.testing.assert_allclose(outs[0], outs[1], rtol=2e-2, atol=2e-2)
+print("ok")
+"""
+
+
+def test_decode_on_a_model_mesh_matches_one_device():
+    """Decode on a 4-way 'model' mesh matches one device in both cache
+    layouts: A (KV heads sharded; the token's K/V row is scattered in
+    place) and B (too few KV heads, so the cache is sequence-sharded and
+    the token is written by the onehot select). Four host devices exist
+    only in a fresh process."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run([sys.executable, "-c", _MESH_DECODE], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0 and out.stdout.strip().endswith("ok"), \
+        out.stderr[-3000:]

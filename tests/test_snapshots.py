@@ -307,7 +307,7 @@ def test_provenance_catches_tampering(snap_run):
 # SnapshotStore unit behavior
 # --------------------------------------------------------------------------- #
 def _entry(gid, base, plen, val=1.0):
-    rows = np.full((2, 3, plen - base, 4), val, np.float32)
+    rows = np.full((2, plen - base, 3, 4), val, np.float32)
     return {"gid": gid, "rid": gid, "slot": 0, "base": base,
             "prefix_len": plen, "cache": {"L0.k": rows},
             "bytes": int(rows.nbytes), "plen": plen, "generated": [],
@@ -321,8 +321,8 @@ def test_store_merges_deltas_contiguously(tmp_path):
     assert st.since(0) == {7: 9}
     rec = st.lookup(7)
     merged = rec["cache"]["L0.k"]
-    assert merged.shape[2] == 9
-    assert (merged[:, :, :5] == 1.0).all() and (merged[:, :, 5:] == 2.0).all()
+    assert merged.shape[1] == 9
+    assert (merged[:, :5] == 1.0).all() and (merged[:, 5:] == 2.0).all()
     with pytest.raises(AssertionError):
         st.put(0, [_entry(7, 7, 12)], tick=12)     # gap in the delta chain
 
@@ -352,8 +352,8 @@ def test_store_crash_durability_matrix(tmp_path):
     rec = st.lookup(4)
     assert st.stats["disk_loads"] == 1
     got = rec["cache"]["L0.k"]
-    assert got.shape[2] == 6
-    assert (got[:, :, :4] == 3.0).all() and (got[:, :, 4:] == 5.0).all()
+    assert got.shape[1] == 6
+    assert (got[:, :4] == 3.0).all() and (got[:, 4:] == 5.0).all()
     # reassign moves ownership; drop removes the on-disk dir too
     st.reassign(4, 2)
     assert st.since(2) == {4: 6} and st.since(0) == {}
@@ -387,10 +387,37 @@ def test_engine_export_import_round_trip(setup):
     P = e["prefix_len"]
     for k in src:
         np.testing.assert_array_equal(
-            np.asarray(src[k][:, e["slot"], :, :P]),
-            np.asarray(dst[k][:, 2, :, :P]))
+            np.asarray(src[k][:, e["slot"], :P]),
+            np.asarray(dst[k][:, 2, :P]))
     assert other.snapshot_stats["restores"] == 1
     assert other.snapshot_stats["restored_tokens"] == P
+
+
+@pytest.mark.parametrize("superstep", [1, 4])
+def test_snapshot_import_continues_after_donated_steps(setup, superstep):
+    """KV rows exported from an engine whose step programs update the cache
+    in place, imported into another engine's slot, continue the request
+    with exactly the tokens an uninterrupted serve emits."""
+    cfg, params = setup
+    scfg = _scfg(superstep=superstep)
+    prompt = np.random.default_rng(4).integers(0, cfg.vocab_size, 13)
+    ref = ServeEngine(cfg, params, scfg)
+    rid = ref.add_request(prompt, 12, gid=0)
+    want = ref.run_until_done()[rid]
+    src = ServeEngine(cfg, params, scfg)
+    src.add_request(prompt, 12, gid=0)
+    for _ in range(3):
+        src.step()
+    e = src.export_kv_snapshot()[0]
+    assert e["generated"] and e["prefix_len"] > len(prompt) - 1
+    dst = ServeEngine(cfg, params, scfg)
+    full = np.concatenate([prompt, np.asarray(e["generated"], np.int32)])
+    rid = dst.add_request(full, 12 - len(e["generated"]), gid=0, restore={
+        "prefix_len": e["prefix_len"], "cache": e["cache"],
+        "bytes": e["bytes"], "snapshot_step": 0})
+    got = dst.run_until_done()[rid]
+    assert e["generated"] + got == want
+    assert dst.snapshot_stats["restored_tokens"] == e["prefix_len"]
 
 
 # --------------------------------------------------------------------------- #

@@ -12,7 +12,9 @@ this file.
 """
 import dataclasses
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ from repro.kernels.mamba_chunk import mamba_chunk
 from repro.kernels.pim_matvec import pim_matvec
 from repro.models import transformer as T
 from repro.models.params import abstract_params
+from repro.serve import engine
 
 V5E_HBM_BYTES = 16 * 2**30
 SLOTS, MAX_LEN, CHUNK = 8, 2048, 256          # chip_smoke.py's serving shape
@@ -45,7 +48,8 @@ def one_chip():
 
 @pytest.fixture(scope="module")
 def compile_for_chip(one_chip):
-    """``compile_for_chip(fn, *shapes)`` -> the compiled program. The
+    """``compile_for_chip(fn, *shapes)`` -> the compiled program; a
+    function that is already jitted keeps its own options (donation). The
     persistent compilation cache is off meanwhile: an entry compiled for a
     described chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -57,7 +61,8 @@ def compile_for_chip(one_chip):
         placed = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                            sharding=one_chip), shapes)
-        return jax.jit(fn).lower(*placed).compile()
+        jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+        return jitted.lower(*placed).compile()
 
     yield compile_
     jax.config.update("jax_enable_compilation_cache", was_on)
@@ -156,3 +161,81 @@ def test_olmo_serving_step_fits_one_chip(compile_for_chip, step, use_pallas):
              + mem.output_size_in_bytes)
     assert total < V5E_HBM_BYTES, (step, total / 2**30)
     assert _has_kernel(compiled) == use_pallas
+
+
+def _engine_program(cfg, step):
+    """The engine's own jitted ``step`` program, with its donation, and its
+    arguments at the serving shape: (fn, args, cache, weights)."""
+    params = abstract_params(T.param_defs(cfg))
+    cache = abstract_params(T.cache_defs(cfg, SLOTS, MAX_LEN))
+    i32 = _s((SLOTS,), jnp.int32)
+    if step == "decode_and_sample":
+        fn = engine._jit_decode_sample(cfg, 0.0, None, MAX_LEN)
+        args = (params, cache, i32, i32, _s((SLOTS,), jnp.bool_), i32, i32,
+                _s((2,), jnp.uint32))
+    elif step == "prefill_chunk":
+        fn = engine._jit_prefill(cfg, CHUNK)
+        args = (params, _s((SLOTS, CHUNK), jnp.int32), cache,
+                _s((SLOTS, CHUNK), jnp.bool_))
+    else:
+        fn, args, params = engine.reset_slots, (cache, i32), {}
+    return fn, args, cache, params
+
+
+def _nbytes(tree) -> int:
+    return sum(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(tree))
+
+
+# one HLO instruction: its name, single-array result type and opcode
+_INSTR = re.compile(r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+
+
+def _whole_cache_writers(hlo: str, leaf_elems: int):
+    """Instructions outside fusion bodies whose result has as many elements
+    as a cache leaf and is neither a view (parameter, get-tuple-element,
+    bitcast) nor a fusion that updates an operand in place."""
+    bad, in_fusion = [], False
+    for line in hlo.splitlines():
+        if line.endswith("{") and "->" in line:
+            in_fusion = line.lstrip("%").startswith("fused")
+            continue
+        m = _INSTR.match(line)
+        if in_fusion or m is None:
+            continue
+        dims = [int(d) for d in m.group(2).split(",") if d]
+        if math.prod(dims) != leaf_elems:
+            continue
+        op = m.group(3)
+        if op in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        if op == "fusion" and '"aliasing_operands"' in line:
+            continue
+        bad.append(line.strip()[:160])
+    return bad
+
+
+@pytest.mark.parametrize("step", ["decode_and_sample", "prefill_chunk",
+                                  "reset_slots"])
+def test_olmo_step_updates_cache_in_place(compile_for_chip, step):
+    """The engine's olmo-1b decode, prefill and admission-reset programs
+    at full width donate the KV cache and write into it in place: the
+    whole cache aliases an output, temporaries stay under one layer's K+V,
+    no instruction writes a fresh whole-cache-sized buffer, and the
+    program holds the weights plus ONE cache, not two."""
+    cfg = get_arch("olmo-1b")
+    fn, args, cache, params = _engine_program(cfg, step)
+    compiled = compile_for_chip(fn, *args)
+    mem = compiled.memory_analysis()
+    cache_b, weights_b = _nbytes(cache), _nbytes(params)
+    assert mem.alias_size_in_bytes >= cache_b, (mem.alias_size_in_bytes,
+                                                cache_b)
+    assert mem.temp_size_in_bytes < cache_b // cfg.num_layers, \
+        mem.temp_size_in_bytes
+    leaf = cache["pos0"]["k"]
+    assert _whole_cache_writers(compiled.as_text(),
+                                math.prod(leaf.shape)) == []
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert abs(held - (weights_b + cache_b)) < 0.03 * (weights_b + cache_b), \
+        (held / 2**30, (weights_b + cache_b) / 2**30)
