@@ -44,6 +44,7 @@ class Record:
     sent: float
     handle: object                   # the engine's Request (for .done)
     left_queue: float = float("nan")  # start of the step that admitted it
+    slot: int = -1                   # its engine slot (-1: not seen in one)
     times: List[float] = field(default_factory=list)
     tokens: List[int] = field(default_factory=list)
 
@@ -135,8 +136,10 @@ def _step(eng, win: Window, t0: float) -> List[int]:
         out = eng.step()
     end = time.perf_counter() - t0
     left = sorted(queued - system.queued_ids(eng))
+    slots = system.slots(eng) if left else {}
     for rid in left:
         win.records[rid].left_queue = start
+        win.records[rid].slot = slots.get(rid, -1)
     toks = []
     finished = []
     for rid, tok in out:

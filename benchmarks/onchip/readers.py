@@ -44,6 +44,23 @@ class Run:
             return r.times
         return [t for t in r.times if t <= self.seconds]
 
+    def token_gaps(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Seconds between consecutive counted tokens of each request, and
+        for each gap whether a step that admitted a request lies in it (an
+        admission gap: the request waited on another's prefill) or not
+        (a decode gap)."""
+        steps = self.win.steps
+        admits = np.cumsum([bool(st.admitted) for st in steps])
+        at = {tok: i for i, st in enumerate(steps) for tok in st.tokens}
+        gaps, admitting = [], []
+        for r in self.requests():
+            t = self.token_times(r)
+            for j in range(1, len(t)):
+                gaps.append(t[j] - t[j - 1])
+                admitting.append(admits[at[r.rid, j]]
+                                 > admits[at[r.rid, j - 1]])
+        return np.array(gaps), np.array(admitting, bool)
+
     def counter_delta(self, group: str, key: Optional[str] = None) -> int:
         a, b = self.counters["start"][group], self.counters["end"][group]
         return b - a if key is None else b[key] - a[key]

@@ -1,15 +1,15 @@
-"""Plain reference of the served architectures, and the check that decides
-``correct``.
+"""What every architecture's plain reference shares, and the check that
+decides ``correct``.
 
-The reference is a straightforward float32 forward pass of the published
-architecture (pre-norm decoder with OLMo's LayerNorm, which has no scale
-and no bias; RoPE on the first and second half of each head; grouped-query
-causal attention; SwiGLU MLP), written here in
-``jax.numpy`` with every matrix product at ``Precision.HIGHEST``. It imports
-nothing of the program and reads only the benchmark's own weights
-(``weights.py``). It runs one layer at a time, and attention in blocks of
-queries, so that it fits beside the served model's bf16 weights once the
-engine is freed.
+An architecture's reference (``archs/<name>.py``, ``logits_at``) is a
+straightforward float32 forward pass of the published architecture in
+``jax.numpy``, with every matrix product through ``mm`` at
+``Precision.HIGHEST``. It imports nothing of the program and reads only
+the benchmark's own weights (``weights.py``). It runs one layer at a
+time, and attention in blocks of queries, so that it fits beside the
+served model's bf16 weights once the engine is freed. Shared here: ``mm``,
+RoPE on the first and second half of each head (``rope``), and blocked
+causal grouped-query attention (``attention``).
 
 ``mode="fp8"`` is the control: every matrix product rounds both operands to
 float8_e4m3fn with one absmax scale per tensor, the precision below the
@@ -20,19 +20,16 @@ prompt followed by the served tokens, and at each served position takes
 the gap between its best logit and the logit of the token the engine
 served. The widest such gap is compared with the cell's limit. Greedy
 decoding in bf16 serves the reference's best token or one within bf16
-rounding of it; a wrong cache, position or layer moves the served token
-far below the best.
+rounding of it; a wrong cache, position, layer or scale moves the served
+token far below the best.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from weights import dims
 
 QUERY_BLOCK = 256
 
@@ -44,20 +41,14 @@ def _fp8(x):
     return q * scale
 
 
-def _mm(spec, a, b, mode):
+def mm(spec, a, b, mode):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     if mode == "fp8":
         a, b = _fp8(a), _fp8(b)
     return jnp.einsum(spec, a, b, precision=jax.lax.Precision.HIGHEST)
 
 
-def _norm(x, eps):
-    mu = jnp.mean(x, -1, keepdims=True)
-    var = jnp.mean((x - mu) ** 2, -1, keepdims=True)
-    return (x - mu) * jax.lax.rsqrt(var + eps)
-
-
-def _rope(x, theta):
+def rope(x, theta):
     """x: (R, S, heads, hd) at positions 0..S-1; rotate-half layout."""
     S, hd = x.shape[1], x.shape[-1]
     half = hd // 2
@@ -68,17 +59,12 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "mode"))
-def _layer(x, layers, i, *, eps, theta, mode):
-    """One decoder layer of the reference. x: (R, S, d) float32."""
-    w = {k: jax.lax.dynamic_index_in_dim(v, i, 0, keepdims=False)
-         for k, v in layers.items()}
-    R, S, _ = x.shape
-    h = _norm(x, eps)
-    q = _rope(_mm("rsd,dhk->rshk", h, w["wq"], mode), theta)
-    k = _rope(_mm("rsd,dhk->rshk", h, w["wk"], mode), theta)
-    v = _mm("rsd,dhk->rshk", h, w["wv"], mode)
-    H, KH, hd = q.shape[2], k.shape[2], q.shape[3]
+def attention(q, k, v, mode):
+    """Causal grouped-query attention over positions 0..S-1, in blocks of
+    ``QUERY_BLOCK`` queries. q (R, S, H, hd); k, v (R, S, KH, hd), with H a
+    multiple of KH. Returns (R, S, H, hd)."""
+    R, S, H, hd = q.shape
+    KH = k.shape[2]
     G = H // KH
     bq = min(QUERY_BLOCK, S)
     kpos = jnp.arange(S)
@@ -86,44 +72,15 @@ def _layer(x, layers, i, *, eps, theta, mode):
     def block(b):
         qb = jax.lax.dynamic_slice_in_dim(q, b * bq, bq, 1)
         qb = qb.reshape(R, bq, KH, G, hd)
-        s = _mm("rqkgh,rckh->rkgqc", qb, k, mode) / math.sqrt(hd)
+        s = mm("rqkgh,rckh->rkgqc", qb, k, mode) / math.sqrt(hd)
         qpos = b * bq + jnp.arange(bq)
         vis = qpos[:, None] >= kpos[None, :]
         s = jnp.where(vis[None, None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return _mm("rkgqc,rckh->rqkgh", p, v, mode).reshape(R, bq, H, hd)
+        return mm("rkgqc,rckh->rqkgh", p, v, mode).reshape(R, bq, H, hd)
 
     o = jax.lax.map(block, jnp.arange(S // bq))          # (nb, R, bq, H, hd)
-    o = jnp.moveaxis(o, 0, 1).reshape(R, S, H, hd)
-    x = x + _mm("rshk,hkd->rsd", o, w["wo"], mode)
-    h = _norm(x, eps)
-    g = _mm("rsd,df->rsf", h, w["w_gate"], mode)
-    u = _mm("rsd,df->rsf", h, w["w_up"], mode)
-    return x + _mm("rsf,fd->rsd", jax.nn.silu(g) * u, w["w_down"], mode)
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "mode"))
-def _head(x, pos, head, *, eps, mode):
-    """Logits (R, P, V) at positions ``pos`` (R, P) of x (R, S, d)."""
-    sel = jnp.take_along_axis(x, pos[..., None], axis=1)
-    h = _norm(sel, eps)
-    return _mm("rpd,dv->rpv", h, head, mode)
-
-
-def logits_at(cfg: dict, norm: dict, w: dict, tokens: np.ndarray,
-              pos: np.ndarray, mode: str = "f32"):
-    """Reference logits (R, P, V) float32 on the device. tokens (R, S)
-    int32 with S a multiple of the query block (the tail is padding, which
-    causal attention never lets an earlier position see); pos (R, P)."""
-    if norm["kind"] != "layernorm_nonparametric":
-        raise ValueError(f"the reference has no {norm['kind']!r} norm")
-    m = dims(cfg)
-    eps = float(norm["eps"])
-    x = jnp.take(w["embed"], jnp.asarray(tokens), axis=0).astype(jnp.float32)
-    for i in range(m["layers"]):
-        x = _layer(x, w["layers"], i, eps=eps, theta=m["theta"], mode=mode)
-    head = w["embed"].T if m["tied"] else w["lm_head"]
-    return _head(x, jnp.asarray(pos), head, eps=eps, mode=mode)
+    return jnp.moveaxis(o, 0, 1).reshape(R, S, H, hd)
 
 
 @jax.jit
@@ -157,19 +114,20 @@ def pack(samples, rows: int, seq_len: int, positions: int):
     return tokens, pos, served, mask
 
 
-def served_gap(cfg, norm, w, samples, rows, seq_len, positions,
+def served_gap(logits_at, conf, w, samples, rows, seq_len, positions,
                control: bool = False) -> dict:
     """The widest gap of the served tokens below the float32 reference's
-    best logit. With ``control``, also the widest gap of the tokens that
-    the fp8 control would put first at the same positions."""
+    best logit; ``logits_at`` is the architecture's reference. With
+    ``control``, also the widest gap of the tokens that the fp8 control
+    would put first at the same positions."""
     tokens, pos, served, mask = pack(samples, rows, seq_len, positions)
-    ref = logits_at(cfg, norm, w, tokens, pos, "f32")
+    ref = logits_at(conf, w, tokens, pos, "f32")
     gaps = np.asarray(_gaps(ref, jnp.asarray(served), jnp.asarray(mask)))
     out = {"served_gap": float(gaps.max()),
            "positions": int(mask.sum()),
            "argmax_differs": int(((gaps > 0) & mask).sum())}
     if control:
-        low = logits_at(cfg, norm, w, tokens, pos, "fp8")
+        low = logits_at(conf, w, tokens, pos, "fp8")
         first = jnp.argmax(low, -1).astype(jnp.int32)
         del low
         cg = np.asarray(_gaps(ref, first, jnp.asarray(mask)))

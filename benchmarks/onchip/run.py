@@ -41,9 +41,14 @@ import device  # noqa: E402
 TRACE_SECONDS = 8.0        # a --trace 1 run profiles the window's last 8 s
 
 
-def sample_finished(win, rows: int, seed: int):
-    """The requests the check compares: the longest finished one and
-    others drawn from the seed, ``rows`` in all."""
+def sample_finished(win, rows: int, slots: int, seed: int):
+    """The requests the check compares, ``rows`` in all: the longest
+    finished one, then one from each part of the batch that the sample
+    does not hold yet (even and odd slots, in the lower and the upper
+    half of the ``slots``), then any others, each drawn in an order from
+    the seed. A fault confined to part of the batch, such as a step that
+    leaves every other slot out, shows in the sample whenever a request
+    of that part finished."""
     import numpy as np
     done = [r for r in win.records.values() if r.done and r.tokens]
     if not done:
@@ -52,8 +57,21 @@ def sample_finished(win, rows: int, seed: int):
     longest = max(done, key=lambda r: (len(r.prompt) + len(r.tokens), -r.rid))
     rest = [r for r in done if r is not longest]
     rng = np.random.default_rng((seed & (2**64 - 1)) ^ 0x5EED)
-    pick = rng.permutation(len(rest))[:rows - 1]
-    return [longest] + [rest[i] for i in sorted(pick)]
+    order = [rest[i] for i in rng.permutation(len(rest))]
+
+    def part(r):
+        return None if r.slot < 0 else (r.slot % 2, 2 * r.slot // slots)
+
+    picked, held = [], {part(longest)}
+    for r in order:
+        if len(picked) < rows - 1 and part(r) is not None \
+                and part(r) not in held:
+            picked.append(r)
+            held.add(part(r))
+    chosen = {r.rid for r in picked}
+    picked += [r for r in order
+               if r.rid not in chosen][:rows - 1 - len(picked)]
+    return [longest] + sorted(picked, key=lambda r: r.rid)
 
 
 def judge(gap, failed: int, limits: dict):
@@ -78,7 +96,6 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     import numpy as np
 
     import driver
-    import flops
     import readers
     import reference
     import system
@@ -87,11 +104,11 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
     import weights
 
     conf, mix = cell.conf, cell.mix
-    norm = conf["norm"]
-    cfg = system.model_config(conf)
+    arch = cells.arch(conf)
+    cfg = system.model_config(conf, arch)
     scfg = system.serve_config(conf)
-    w = weights.make_weights(conf["config"], seed)
-    params = system.program_params(w, cfg)
+    w = weights.make_weights(arch.shapes(conf), seed)
+    params = system.program_params(arch, w, cfg)
     eng = system.make_engine(cfg, scfg, params)
     driver.warm_up(eng, traffic.longest_prompt(mix), cfg.vocab_size)
     sched = traffic.build(mix, seed, seconds, cfg.vocab_size)
@@ -115,7 +132,7 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
         shutil.rmtree(trace_dir, ignore_errors=True)
     on_chip = devices[0].platform == "tpu"
     run = readers.Run(win, setup_s, {"start": before, "end": after},
-                      red if on_chip else None, flops.Counts(conf),
+                      red if on_chip else None, arch.Counts(conf),
                       device.peaks(devices[0].device_kind) if on_chip else {})
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
@@ -127,9 +144,9 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, trace: bool,
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
     check = mix["check"]
-    picked = sample_finished(win, check["requests"], seed)
+    picked = sample_finished(win, check["requests"], scfg.max_slots, seed)
     gap = reference.served_gap(
-        conf["config"], norm, w,
+        arch.logits_at, conf, w,
         [(r.prompt, np.asarray(r.tokens, np.int32)) for r in picked],
         rows=check["requests"], seq_len=scfg.max_len,
         positions=traffic.quantile_lengths(mix["output"],

@@ -1,10 +1,11 @@
 """The system under test, and the only module that imports the program.
 
 It builds the program's model and serving configuration from a
-configuration file, hands the benchmark's weights to the program in the
-layout the program expects, and reads the engine's own counters. Every
-``ServeConfig`` field that the configuration file does not set stays at
-the program's default, so a change to a default is measured.
+configuration file and its architecture module (``archs/``), hands the
+benchmark's weights to the program in the tree the module lays out,
+checked against the program's own, and reads the engine's own counters.
+Every ``ServeConfig`` field that the configuration file does not set stays
+at the program's default, so a change to a default is measured.
 """
 from __future__ import annotations
 
@@ -23,43 +24,47 @@ from repro.models import transformer as T  # noqa: E402
 from repro.models.params import abstract_params  # noqa: E402
 from repro.serve import ServeConfig, ServeEngine  # noqa: E402
 
-from weights import dims  # noqa: E402
+# the epsilon ``models/layers.py``'s ``apply_norm`` applies to each norm,
+# where ``ModelConfig`` has no ``norm_eps`` field to take it from
+PROGRAM_EPS = {"rmsnorm": 1e-6, "layernorm": 1e-5, "np_layernorm": 1e-5}
 
 
-def model_config(conf: dict):
-    """The program's ModelConfig: the registry entry with every size taken
-    from the configuration file."""
+def model_config(conf: dict, arch):
+    """The program's ModelConfig: the registry entry with every field the
+    architecture module reads from the configuration file. The registry
+    entry has to have the norm and activation that the module reads and
+    that the file's ``program`` states, and the program has to honour the
+    file's epsilon: from a ``norm_eps`` field where ``ModelConfig`` has
+    one, and otherwise only at the value it applies."""
     base = get_arch(conf["program"]["arch"])
+    fields = dict(arch.program_fields(conf))
     for key in ("norm", "act"):
-        if getattr(base, key) != conf["program"][key]:
-            raise ValueError(
-                f"registry {base.name!r} has {key}={getattr(base, key)!r}; "
-                f"the configuration states {conf['program'][key]!r}")
-    m = dims(conf["config"])
-    return dataclasses.replace(
-        base, num_layers=m["layers"], d_model=m["d"], num_heads=m["heads"],
-        num_kv_heads=m["kv_heads"], head_dim=m["hd"], d_ff=m["ff"],
-        vocab_size=m["vocab"], rope_theta=m["theta"],
-        tie_embeddings=m["tied"], dtype=conf["config"]["torch_dtype"])
+        for stated in (fields[key], conf["program"][key]):
+            if getattr(base, key) != stated:
+                raise ValueError(
+                    f"registry {base.name!r} has {key}="
+                    f"{getattr(base, key)!r}; the configuration states "
+                    f"{stated!r}")
+    eps = fields.pop("norm_eps")
+    if any(f.name == "norm_eps" for f in dataclasses.fields(base)):
+        fields["norm_eps"] = eps
+    elif eps != PROGRAM_EPS[base.norm]:
+        raise ValueError(
+            f"the configuration states {base.norm} eps {eps}; the program "
+            f"applies {PROGRAM_EPS[base.norm]} and its ModelConfig has no "
+            f"norm_eps field to take another")
+    return dataclasses.replace(base, **fields)
 
 
 def serve_config(conf: dict) -> ServeConfig:
     return ServeConfig(**conf["serve"])
 
 
-def program_params(w: dict, cfg) -> dict:
-    """The benchmark's weights in the program's parameter tree, checked
-    leaf by leaf against the program's own parameter definitions."""
-    L = w["layers"]
-    block = {
-        "attn": {"wq": L["wq"], "wk": L["wk"], "wv": L["wv"], "wo": L["wo"]},
-        "ffn": {"wg": L["w_gate"], "wi": L["w_up"], "wo": L["w_down"]},
-        "norm1": {}, "norm2": {},
-    }
-    embed = {"tok": w["embed"]}
-    if "lm_head" in w:
-        embed["lm_head"] = w["lm_head"]
-    params = {"embed": embed, "blocks": {"pos0": block}, "final_norm": {}}
+def program_params(arch, w: dict, cfg) -> dict:
+    """The benchmark's weights in the program's parameter tree (the
+    architecture module's ``program_tree``), checked leaf by leaf against
+    the program's own parameter definitions."""
+    params = arch.program_tree(w)
     want = abstract_params(T.param_defs(cfg))
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                        params)
@@ -84,6 +89,11 @@ def counters(eng) -> dict:
 
 def queued_ids(eng) -> set:
     return {r.rid for r in eng.queue}
+
+
+def slots(eng) -> dict:
+    """{request id: slot} of the requests resident in the engine."""
+    return {r.rid: i for i, r in enumerate(eng.slot_req) if r is not None}
 
 
 def busy(eng) -> bool:

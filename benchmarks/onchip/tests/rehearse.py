@@ -23,9 +23,12 @@ import cells  # noqa: E402
 import run  # noqa: E402
 
 
-def rehearse(name: str, seed: int, seconds: float, trace: bool = False,
+def rehearse(cell, seed: int, seconds: float, trace: bool = False,
              control: bool = False) -> dict:
-    cell = cells.load(name, rehearsal=True)
+    """One rehearsal run of ``cell``: a name from ``BENCHMARK.json``, or a
+    ``cells.Cell`` already at rehearsal sizes (``cells.shrink``)."""
+    if isinstance(cell, str):
+        cell = cells.load(cell, rehearsal=True)
     return run.run_cell(cell, seed, seconds, trace, jax.devices(),
                         time.perf_counter(), control=control)
 

@@ -11,6 +11,11 @@ must come out with ``correct`` false:
                    one it computed, at one position in four
 
 The exchange between chips cannot fail here: every cell runs on one chip.
+
+half_batch shows only in a request that sat in an odd slot. The check's
+sample holds one whenever such a request finished: it takes one request
+from each part of the batch, even and odd slots of either half
+(``run.sample_finished``).
 """
 import jax.numpy as jnp
 import pytest

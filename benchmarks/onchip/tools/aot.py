@@ -5,8 +5,9 @@ without the chip, and print their memory analysis.
 
 Compiles the engine's ``decode_and_sample`` and its ``prefill_chunk`` at
 the last chunk offset of ``max_len``, at the configuration's serve sizes,
-and the reference's float32 layer at the check's sizes for each mix given
-after the configuration name.
+and, where the configuration's architecture module has a
+``reference_layer``, the reference's layer, float32 and the fp8 control,
+at the check's sizes for each mix given after the configuration name.
 """
 from __future__ import annotations
 
@@ -23,7 +24,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-import reference  # noqa: E402
+import cells  # noqa: E402
 import system  # noqa: E402
 import weights  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
@@ -52,7 +53,8 @@ def main(name: str, mixes) -> None:
     one = SingleDeviceSharding(topo.devices[0])
     with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
         conf = json.load(f)
-    cfg = system.model_config(conf)
+    arch = cells.arch(conf)
+    cfg = system.model_config(conf, arch)
     scfg = system.serve_config(conf)
     B, L, C = scfg.max_slots, scfg.max_len, scfg.prefill_chunk
 
@@ -74,21 +76,15 @@ def main(name: str, mixes) -> None:
     pre = E._jit_prefill(cfg, off)
     report(f"{name} prefill_chunk offset {off} {B}x{C}",
            pre.lower(params, toks, cache, valid).compile())
-    w = weights.shapes(conf["config"])
-    layers = {k: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one)
-              for k, (s, _, _) in w["layers"].items()}
-    d = conf["config"]["hidden_size"]
+    if not hasattr(arch, "reference_layer"):
+        return
+    w = weights.abstract(arch.shapes(conf), one)
     for mix in mixes:
         with open(os.path.join(HERE, "traffic", f"{mix}.json")) as f:
             rows = json.load(f)["check"]["requests"]
-        x = jax.ShapeDtypeStruct((rows, L, d), jnp.float32, sharding=one)
-        i = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
-        kw = dict(eps=float(conf["norm"]["eps"]),
-                  theta=float(conf["config"]["rope_theta"]))
         for mode in ("f32", "fp8"):
             report(f"{name} reference layer ({mix}, {rows}x{L}, {mode})",
-                   reference._layer.lower(x, layers, i, mode=mode,
-                                          **kw).compile())
+                   arch.reference_layer(conf, w, rows, L, mode).compile())
 
 
 if __name__ == "__main__":

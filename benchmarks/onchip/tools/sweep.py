@@ -37,10 +37,11 @@ def main(cell_name: str, seconds: float, rates) -> int:
     import traffic
     import weights
     conf = cell.conf
-    cfg = system.model_config(conf)
-    w = weights.make_weights(conf["config"], 1)
+    arch = cells.arch(conf)
+    cfg = system.model_config(conf, arch)
+    w = weights.make_weights(arch.shapes(conf), 1)
     eng = system.make_engine(cfg, system.serve_config(conf),
-                             system.program_params(w, cfg))
+                             system.program_params(arch, w, cfg))
     driver.warm_up(eng, traffic.longest_prompt(cell.mix), cfg.vocab_size)
     print(f"[sweep] {cell_name}: set-up {time.perf_counter() - T0:.1f} s",
           flush=True)
