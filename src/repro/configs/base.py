@@ -18,6 +18,9 @@ from typing import Optional, Tuple
 
 Family = str  # "dense" | "moe" | "ssm" | "hybrid" | "encdec" | "vlm"
 
+# the epsilon each norm applies where a config leaves ``norm_eps`` unset
+NORM_EPS = {"rmsnorm": 1e-6, "layernorm": 1e-5, "np_layernorm": 1e-5}
+
 
 @dataclass(frozen=True)
 class ModelConfig:
@@ -61,6 +64,7 @@ class ModelConfig:
 
     # misc architecture knobs
     norm: str = "rmsnorm"      # "rmsnorm" | "layernorm" | "np_layernorm" (olmo)
+    norm_eps: float = 0.0      # 0 -> NORM_EPS[norm]
     act: str = "silu"          # "silu" | "gelu"
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
@@ -89,6 +93,8 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // max(1, self.num_heads))
         if self.num_kv_heads == 0:
             object.__setattr__(self, "num_kv_heads", self.num_heads)
+        if self.norm_eps == 0:
+            object.__setattr__(self, "norm_eps", NORM_EPS[self.norm])
 
     # ---- derived quantities -------------------------------------------------
     @property

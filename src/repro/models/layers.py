@@ -33,12 +33,12 @@ def apply_norm(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(var + 1e-6)
+        y = xf * jax.lax.rsqrt(var + cfg.norm_eps)
         y = y * p["scale"].astype(jnp.float32)
     else:
         mean = jnp.mean(xf, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
-        y = (xf - mean) * jax.lax.rsqrt(var + 1e-5)
+        y = (xf - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
         if cfg.norm == "layernorm":
             y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
         # np_layernorm (OLMo): no affine params
