@@ -323,10 +323,14 @@ def _layer_kv(cfg: ModelConfig, cache: dict, layer,
     [0, span) (all when None) of every slot, or of the ``slots`` rows
     gathered; an int8 cache is dequantized after the gather."""
     def read(name):
+        if slots is not None:
+            # one gather from the stacked leaf: only the named rows' span
+            # is read, never the layer's other slots
+            return cache[name][layer, slots, :span]
         x = jax.lax.dynamic_index_in_dim(cache[name], layer, 0, keepdims=False)
         if span is not None:
             x = jax.lax.slice_in_dim(x, 0, span, axis=1)
-        return x if slots is None else jnp.take(x, slots, axis=0)
+        return x
     k, v = read("k"), read("v")
     if cfg.kv_dtype == "int8":
         k, v = _dequantize(k, read("k_scale")), _dequantize(v, read("v_scale"))
@@ -347,30 +351,32 @@ def _pallas_flash(q, k, v, **kw):
 # --------------------------------------------------------------------------- #
 def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
                              cache: dict, layer, tok_valid: jax.Array,
-                             offset: int):
-    """One prefill chunk against the slot cache. x: (B, C, d) at global
+                             offset: int,
+                             row_slot: Optional[jax.Array] = None):
+    """One prefill chunk against the slot cache. x: (R, C, d) at global
     positions [offset, offset+C); ``cache`` holds this attention position's
-    stacked leaves and ``layer`` indexes them. Writes the chunk's K/V rows
-    into the cache (``tok_valid`` (B, C) False drops a write, so other
-    slots' rows are untouched) and attends causally over
-    cache[:offset+C] via the flash path — one dispatch covers every
-    admitted slot's chunk instead of B*C decode steps.
+    stacked leaves and ``layer`` indexes them. Row j is cache slot
+    ``row_slot[j]`` ((R,) int32; None: row j is slot j, R = every slot).
+    Writes the chunk's K/V rows into the cache (``tok_valid`` (R, C) False
+    drops a write, so other slots' rows are untouched) and attends
+    causally over the rows' own cache[:offset+C] via the flash path — one
+    dispatch covers R requests' chunks instead of R*C decode steps.
 
-    Returns (out (B, C, d), new_cache). Padding rows (tok_valid False)
-    produce garbage outputs over zero K/V — callers discard them."""
-    B, C, _ = x.shape
+    Returns (out (R, C, d), new_cache). Padding rows (tok_valid False)
+    produce garbage outputs — callers discard them."""
+    R, C, _ = x.shape
     S = cache["k"].shape[2]
-    positions = offset + jnp.broadcast_to(jnp.arange(C)[None], (B, C))
+    positions = offset + jnp.broadcast_to(jnp.arange(C)[None], (R, C))
     q, k_new, v_new = qkv_project(cfg, p, x, positions)
     pos = jnp.where(tok_valid, positions, S)
-    slot = jnp.arange(B)[:, None]
+    slot = (jnp.arange(R) if row_slot is None else row_slot)[:, None]
     cache, _ = _store_kv(
         cfg, cache, lambda leaf, r: _write_rows(leaf, layer, slot, pos, r),
         k_new, v_new)
     # attend over the populated prefix only — the span is static (chunk
     # index is baked into the jitted function), so this is a free slice
     span = min(offset + C, S)
-    k_att, v_att = _layer_kv(cfg, cache, layer, span)
+    k_att, v_att = _layer_kv(cfg, cache, layer, span, slots=row_slot)
     if cfg.use_pallas:
         # the kernel needs the chunk grid to tile the span exactly; a last
         # chunk that overhangs the cache (max_len not a multiple of the

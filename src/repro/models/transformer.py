@@ -541,15 +541,16 @@ def supports_batched_prefill(cfg: ModelConfig) -> bool:
 
 def _apply_block_prefill(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
                          x: jax.Array, cache: dict, layer,
-                         tok_valid: jax.Array, offset: int):
-    """Chunk-of-prompt block. x: (B, C, d). Returns (x, new_cache)."""
+                         tok_valid: jax.Array, offset: int, row_slot):
+    """Chunk-of-prompt block. x: (R, C, d). Returns (x, new_cache)."""
     mixer, ffn = kind
     if mixer != "attn":
         raise NotImplementedError(
             "batched prefill covers attention mixers only")
     h = L.apply_norm(cfg, p["norm1"], x)
     y, new_cache = A.attention_prefill_cached(cfg, p["attn"], h, cache,
-                                              layer, tok_valid, offset)
+                                              layer, tok_valid, offset,
+                                              row_slot)
     x = x + y
     h = L.apply_norm(cfg, p["norm2"], x)
     if ffn == "moe":
@@ -560,11 +561,15 @@ def _apply_block_prefill(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
 
 
 def prefill_chunk(cfg: ModelConfig, params: dict, tokens: jax.Array,
-                  cache: dict, tok_valid: jax.Array, *, offset: int):
-    """One batched-prefill dispatch: tokens (B, C) at global positions
+                  cache: dict, tok_valid: jax.Array,
+                  row_slot: Optional[jax.Array] = None, *, offset: int):
+    """One batched-prefill dispatch: tokens (R, C) at global positions
     [offset, offset+C) run through the full stack; every attention layer
     writes its chunk K/V into the cache (writes masked by ``tok_valid``,
-    so only admitted slots' rows change). Returns the new cache.
+    so only admitted slots' rows change). Row j is cache slot
+    ``row_slot[j]`` ((R,) int32), so a dispatch carries only the rows being
+    prefilled; None is the slot grid, row j = slot j over every slot.
+    Returns the new cache.
 
     Prefill emits no logits: the engine's first generation step feeds the
     last prompt token, so the summarization stage is pure cache fill —
@@ -574,7 +579,7 @@ def prefill_chunk(cfg: ModelConfig, params: dict, tokens: jax.Array,
 
     def apply(kind, p, x, c, layer):
         return _apply_block_prefill(cfg, kind, p, x, c, layer, tok_valid,
-                                    offset)
+                                    offset, row_slot)
 
     return _serve_blocks(cfg, apply, x, cache, params["blocks"])[1]
 

@@ -344,19 +344,18 @@ class MetricsHub:
         step = int(ev["step"])
         chunk, valid = int(ev["chunk"]), int(ev["valid"])
         self.counter("prefill_valid_tokens").inc(valid)
-        # computed token slots per dispatch: the packed grid shrinks to the
-        # rows used; the unpacked grid is always max_slots rows; a
-        # sequential (fallback) event stands for `valid` one-token
-        # full-batch dispatches — the same rules engine.prefill_stats uses
+        # computed token slots per dispatch: a chunk dispatch computes the
+        # rows it carries (``rows``: the packed lanes used, the unpacked
+        # row grid, max_slots on the slot grid); a sequential (fallback)
+        # event stands for `valid` one-token full-batch dispatches — the
+        # same rules engine.prefill_stats uses
         max_slots = int(self.header["serve"]["max_slots"]) if self.header \
             else len(ev["slots"])
-        if ev.get("packed", False):
-            self.counter("prefill_token_slots").inc(int(ev["rows"]) * chunk)
-        elif self.header is not None and \
+        if self.header is not None and not ev.get("packed", False) and \
                 self.header["serve"].get("prefill_mode") == "sequential":
             self.counter("prefill_token_slots").inc(max_slots * valid)
         else:
-            self.counter("prefill_token_slots").inc(max_slots * chunk)
+            self.counter("prefill_token_slots").inc(int(ev["rows"]) * chunk)
         if ev.get("fused", False):
             self.counter("fused_prefill_events").inc()
         else:

@@ -26,13 +26,14 @@ import numpy as np
 @dataclass
 class PrefillJob:
     """An in-flight prefill sub-batch: one admission wave's prompt tokens
-    laid out for chunked dispatch. ``next_chunk`` advances one chunk per
-    ``dispatch_prefill_chunk`` call, so a scheduler can spread a wave's
-    summarization work across engine steps (NeuPIMs-style sub-batch
-    interleaving) instead of running it to completion."""
+    laid out for chunked dispatch, indexed by slot; a dispatch takes the
+    rows of the requests with tokens in its chunk. ``next_chunk`` advances
+    one chunk per ``dispatch_prefill_chunk`` call, so a scheduler can
+    spread a wave's summarization work across engine steps (NeuPIMs-style
+    sub-batch interleaving) instead of running it to completion."""
     wave: List[Tuple[int, object]]      # [(slot, Request), ...]
-    tokens: np.ndarray                  # (B, n_chunks * chunk) int32
-    valid: np.ndarray                   # (B, n_chunks * chunk) bool
+    tokens: np.ndarray                  # (B, n_chunks * chunk) int32, by slot
+    valid: np.ndarray                   # (B, n_chunks * chunk) bool, by slot
     chunk: int
     n_chunks: int
     sub_batch: int                      # wave ordinal (trace sub-batch id)
@@ -53,9 +54,9 @@ class PrefillJob:
 
     def take_completed(self) -> List[Tuple[int, object]]:
         """(slot, req) pairs whose prefill finished since the last call.
-        The unpacked layout fills every slot's row in lockstep, so the whole
-        wave completes with the final chunk; ``PackedPrefillJob`` overrides
-        this with per-dispatch completions."""
+        The unpacked layout fills the wave's rows chunk by chunk, so the
+        whole wave completes with the final chunk; ``PackedPrefillJob``
+        overrides this with per-dispatch completions."""
         if self.done and not self._wave_taken:
             self._wave_taken = True
             return list(self.wave)

@@ -1,11 +1,10 @@
 """Prefill packing planner: lay an admission wave out in PACKED chunk rows.
 
 The unpacked layout (``ServeEngine.build_prefill_job``) gives every admitted
-slot its own row in a fixed (max_slots, chunk) dispatch grid and pads each
-row to the wave's longest prompt, so a mixed wave dispatches mostly-empty
-grids — the per-dispatch valid-token fraction the paper's Fig. 10 occupancy
-assumes is lost exactly on the workloads PIM serving targets (many short
-summarization prompts).
+request its own row per chunk and pads each row to the chunk's width, so a
+wave of short prompts dispatches mostly-empty rows — the per-dispatch
+valid-token fraction the paper's Fig. 10 occupancy assumes is lost exactly
+on the workloads PIM serving targets (many short summarization prompts).
 
 ``plan_packed_job`` instead treats a dispatch as up to ``max_slots`` *lanes*
 of ``chunk`` columns — decoupled from the slot grid, since every token

@@ -3,8 +3,11 @@
 The engine is the TPU realization of the paper's two-phase inference flow:
   * summarization (prefill) — compute-bound: admitted prompts run as whole
     chunks through the flash-attention path (``T.prefill_chunk``), filling
-    every slot's KV cache in O(ceil(S/chunk)) dispatches instead of S
-    teacher-forced decode steps;
+    their slots' KV cache in O(ceil(S/chunk)) dispatches instead of S
+    teacher-forced decode steps; a dispatch carries only the rows of the
+    requests with tokens in its chunk, each row naming its slot, as many
+    as fill ``PREFILL_ROW_TOKENS`` (one 256-token row; every slot at short
+    chunks);
   * generation (decode) — bandwidth-bound: one jit'd fused
     decode+sample+terminate dispatch across all active slots per emitted
     token; the only host sync is fetching the (token, done, len) triple —
@@ -115,6 +118,21 @@ class Request:
     t_done: Optional[float] = None
 
 
+# Prompt tokens one prefill dispatch should carry. A bf16 matmul over M
+# token rows does M FLOP per weight byte; the v5e ridge is 197e12 / 819e9
+# ~ 240 FLOP/B, so one row of a 256-token chunk already keeps the MXU as
+# busy as sixteen, and more rows per dispatch buy at most one re-read of
+# the weights. A dispatch carries ceil(256 / prefill_chunk) rows, at most
+# one per slot.
+PREFILL_ROW_TOKENS = 256
+
+
+def prefill_rows(max_slots: int, chunk: int) -> int:
+    """Rows per unpacked prefill dispatch: enough to reach
+    ``PREFILL_ROW_TOKENS``, never more than the slots."""
+    return min(max_slots, -(-PREFILL_ROW_TOKENS // chunk))
+
+
 # Jitted entry points are cached at module level keyed by the (frozen,
 # hashable) ModelConfig: every ServeEngine for the same config shares one
 # compiled decode step and one compiled prefill per chunk index, instead of
@@ -129,6 +147,8 @@ def _jit_decode(cfg: ModelConfig):
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill(cfg: ModelConfig, offset: int):
+    """One jitted prefill per chunk offset. Called with four arguments it
+    runs the slot grid; with a fifth, ``row_slot``, the row grid."""
     return jax.jit(functools.partial(T.prefill_chunk, cfg, offset=offset),
                    donate_argnums=2)
 
@@ -311,6 +331,7 @@ class ServeEngine:
         self._decode_sample = _jit_decode_sample(
             cfg, scfg.temperature, scfg.eos_token, scfg.max_len)
         self._batched_ok = T.supports_batched_prefill(cfg)
+        self._prefill_rows = prefill_rows(B, scfg.prefill_chunk)
         self.scheduler = make_scheduler(self.effective_policy,
                                         sub_batch=scfg.sub_batch,
                                         map_dims=scfg.map_dims,
@@ -329,7 +350,8 @@ class ServeEngine:
         self.superstep_tokens = 0     # decode rounds resolved via supersteps
         self._superstep_seq = 0       # superstep dispatch ordinal (trace)
         # padding-waste accounting for the batched prefill path:
-        # token_slots = B*C rows computed per dispatch; valid = useful ones;
+        # token_slots = R*C tokens computed per dispatch of R rows; valid =
+        # useful ones;
         # kv_cells = attended KV cells per computed row summed over prefill
         # dispatches (rows * attended span) — what the per-lane prefix-span
         # segregation in the packing planner reduces
@@ -658,10 +680,12 @@ class ServeEngine:
             return admitted
 
     def build_prefill_job(self, wave) -> Optional[PrefillJob]:
-        """Lay a wave's prompt tokens out for chunked dispatch. None when
-        the wave has no cache tokens to write (all single-token prompts).
-        With ``pack=True`` the wave is first-fit-decreasing packed into
-        chunk rows (``plan_packed_job``) instead of one row per slot."""
+        """Lay a wave's prompt tokens out for chunked dispatch, indexed by
+        slot; ``dispatch_prefill_chunk`` takes from it only the rows of
+        requests with tokens in the chunk. None when the wave has no cache
+        tokens to write (all single-token prompts). With ``pack=True`` the
+        wave is first-fit-decreasing packed into chunk rows
+        (``plan_packed_job``) instead."""
         B, C = self.scfg.max_slots, self.scfg.prefill_chunk
         if self.scfg.pack:
             return plan_packed_job(wave, max_slots=B, chunk=C,
@@ -692,23 +716,23 @@ class ServeEngine:
         return _jit_prefill(self.cfg, chunk_idx * self.scfg.prefill_chunk)
 
     def _account_chunk_prefill(self, job: PrefillJob, c: int,
-                               vc: np.ndarray, *, overlap: bool,
-                               fused: bool) -> None:
-        """Stats + route + trace event for one UNPACKED chunk dispatch
-        (shared by the standalone and fused paths)."""
-        B, C = self.scfg.max_slots, job.chunk
-        self.prefill_stats["token_slots"] += B * C
+                               vc: np.ndarray, slots: List[int], *,
+                               overlap: bool, fused: bool) -> None:
+        """Stats + route + trace event for one UNPACKED chunk dispatch of
+        ``vc.shape[0]`` rows carrying ``slots`` (shared by the standalone
+        and fused paths)."""
+        R, C = vc.shape[0], job.chunk
+        self.prefill_stats["token_slots"] += R * C
         self.prefill_stats["valid_tokens"] += int(vc.sum())
-        self.prefill_stats["kv_cells"] += B * (c * C + C)
+        self.prefill_stats["kv_cells"] += R * (c * C + C)
         entry = self._phase_entry("summarization", int(vc.sum()),
-                                  len(job.wave))
+                                  len(slots))
         if self.recorder is not None:
             self.recorder.on_prefill(
                 self.step_idx, offset=c * C, chunk=C,
-                valid=int(vc.sum()), kv=c * C + C,
-                slots=[int(s) for s, _ in job.wave if vc[s].any()],
+                valid=int(vc.sum()), kv=c * C + C, slots=slots,
                 route=entry, sub_batch=job.sub_batch, overlap=overlap,
-                fused=fused)
+                fused=fused, rows=R)
 
     def _account_packed_prefill(self, job: PackedPrefillJob, d, *,
                                 overlap: bool, fused: bool) -> None:
@@ -730,23 +754,44 @@ class ServeEngine:
     def dispatch_prefill_chunk(self, job: PrefillJob, *,
                                overlap: bool = False) -> None:
         """Run the job's next chunk through the batched flash prefill path.
-        ``overlap=True`` marks the dispatch as co-scheduled with this step's
-        decode (recorded in the trace; the replay merges the two streams)."""
+        The chunk's rows with prompt tokens go out in dispatches of
+        ``prefill_rows`` rows, each row naming its slot, the last padded
+        with rows whose writes drop; at one row per slot the dispatch is
+        the slot grid. ``overlap=True`` marks the dispatches as
+        co-scheduled with this step's decode (recorded in the trace; the
+        replay merges the two streams)."""
         if isinstance(job, PackedPrefillJob):
             return self._dispatch_packed_chunk(job, overlap=overlap)
         c, C = job.next_chunk, job.chunk
         job.next_chunk += 1
-        vc = job.valid[:, c * C:(c + 1) * C]
-        if not vc.any():
+        window = slice(c * C, (c + 1) * C)
+        vc = job.valid[:, window]
+        slots = [int(s) for s, _ in job.wave if vc[s].any()]
+        if not slots:
             return
-        with span(self.spans, "serve.prefill"):
-            fn = self._get_prefill_fn(c)
-            self.cache = fn(self.params,
-                            jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
-                            self.cache, jnp.asarray(vc))
-            self.dispatch_counts["prefill"] += 1
-            self._account_chunk_prefill(job, c, vc, overlap=overlap,
-                                        fused=False)
+        R = self._prefill_rows
+        fn = self._get_prefill_fn(c)
+        for g in range(0, len(slots), R):
+            group = slots[g:g + R]
+            with span(self.spans, "serve.prefill"):
+                if R == self.scfg.max_slots:   # the slot grid: row i, slot i
+                    valid = vc
+                    self.cache = fn(self.params,
+                                    jnp.asarray(job.tokens[:, window]),
+                                    self.cache, jnp.asarray(valid))
+                else:
+                    row_slot = np.full(R, group[0], np.int32)
+                    row_slot[:len(group)] = group
+                    valid = vc[row_slot]
+                    valid[len(group):] = False
+                    self.cache = fn(
+                        self.params,
+                        jnp.asarray(job.tokens[row_slot, window]),
+                        self.cache, jnp.asarray(valid),
+                        jnp.asarray(row_slot))
+                self.dispatch_counts["prefill"] += 1
+                self._account_chunk_prefill(job, c, valid, group,
+                                            overlap=overlap, fused=False)
 
     def _dispatch_packed_chunk(self, job: PackedPrefillJob, *,
                                overlap: bool = False) -> None:
@@ -829,7 +874,8 @@ class ServeEngine:
                 self.recorder.on_prefill(
                     self.step_idx, offset=0, chunk=n_valid, valid=n_valid,
                     kv=n_valid, slots=[slot], route=entry,
-                    sub_batch=self.wave_count - 1, overlap=False)
+                    sub_batch=self.wave_count - 1, overlap=False,
+                    rows=self.scfg.max_slots)
 
     # ---- generation phase: one fused decode dispatch across ready slots ---- #
     def _ready_active(self) -> Tuple[Optional[np.ndarray], int]:
@@ -919,8 +965,9 @@ class ServeEngine:
                     self.params, self.cache,
                     jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
                     jnp.asarray(vc), *common)
-                self._account_chunk_prefill(job, c, vc, overlap=True,
-                                            fused=True)
+                self._account_chunk_prefill(
+                    job, c, vc, [int(s) for s, _ in job.wave if vc[s].any()],
+                    overlap=True, fused=True)
             self.dispatch_counts["fused"] += 1
             self._start_fetch(fetch)
             return PendingDecode(fetch=fetch, active_np=active_np, n_tok=n_tok,

@@ -140,7 +140,8 @@ class TraceRecorder:
                    packed: bool = False, segments: Optional[int] = None,
                    rows: Optional[int] = None,
                    fused: bool = False) -> None:
-        # unpacked layout: one row per dispatched slot, one segment per row
+        # one segment per dispatched slot; ``rows`` is the grid the dispatch
+        # computed (the engine passes it), one row per slot when not given
         if segments is None:
             segments = len(slots)
         if rows is None:

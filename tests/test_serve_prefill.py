@@ -1,4 +1,6 @@
 """Phase-separated serving: batched prefill equivalence + dispatch shape."""
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -7,6 +9,7 @@ from repro.configs import get_arch
 from repro.models import transformer as T
 from repro.models.params import init_params
 from repro.serve import ServeConfig, ServeEngine
+from repro.serve import engine as engine_mod
 from repro.trace import TraceRecorder
 
 KEY = jax.random.PRNGKey(0)
@@ -248,3 +251,180 @@ def test_pallas_prefill_refuses_ragged_tail(setup):
     step = functools.partial(T.prefill_chunk, cfg, offset=32)
     with pytest.raises(ValueError, match="does not tile"):
         jax.eval_shape(step, params, tokens, cache, valid)
+
+
+def test_prefill_chunk_row_slot_matches_slot_grid(setup):
+    """Unit-level: ``T.prefill_chunk`` over rows that name their slots, one
+    row per dispatch or several with a padding row, writes the same K/V as
+    the slot-grid call, and leaves the other slots' rows as they were."""
+    import jax.numpy as jnp
+    cfg, params = setup
+    B, L, C = 4, 64, 8
+    rng = np.random.default_rng(11)
+    plens = {2: 14, 0: 7}                       # slots 1 and 3 stay out
+    toks = np.zeros((B, 2 * C), np.int32)
+    valid = np.zeros((B, 2 * C), bool)
+    for slot, n in plens.items():
+        toks[slot, :n - 1] = rng.integers(0, cfg.vocab_size, n - 1)
+        valid[slot, :n - 1] = True
+    leaves, tree = jax.tree.flatten(init_params(T.cache_defs(cfg, B, L), KEY))
+    keys = jax.random.split(KEY, len(leaves))
+    cache0 = jax.tree.unflatten(tree, [
+        jax.random.normal(k, a.shape).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+    step = {c: jax.jit(functools.partial(T.prefill_chunk, cfg, offset=c * C))
+            for c in range(2)}
+
+    def run(groups):
+        cache = cache0
+        for c in range(2):
+            w = slice(c * C, (c + 1) * C)
+            for rows in groups:
+                if rows is None:
+                    cache = step[c](params, toks[:, w], cache, valid[:, w])
+                    continue
+                v = valid[rows, w]
+                v[len(set(rows)):] = False       # a repeated row pads
+                cache = step[c](params, toks[rows, w], cache, v,
+                                jnp.asarray(rows, jnp.int32))
+        return cache
+
+    want = run([None])
+    for groups in ([[2], [0]], [[0, 2]], [[2, 0, 2]]):
+        got = run(groups)
+        for pos in want:
+            for k in want[pos]:
+                a = np.asarray(want[pos][k], np.float32)
+                b = np.asarray(got[pos][k], np.float32)
+                np.testing.assert_array_equal(a, b,
+                                              err_msg=f"{groups} {pos}/{k}")
+                np.testing.assert_array_equal(
+                    b[:, [1, 3]],
+                    np.asarray(cache0[pos][k], np.float32)[:, [1, 3]])
+
+
+# (prefill_chunk, max_slots, max_len, prompt lengths): one row per dispatch
+# at 256-token chunks, two rows (the last group padded) at 128, the slot
+# grid at 8; every set spans several chunks and mixes lengths
+GRIDS = {
+    "rows1": (256, 4, 512, (300, 40, 470, 260, 3, 130)),
+    "rows2": (128, 4, 512, (300, 40, 470, 260, 3, 130)),
+    "slot_grid": (8, 4, 64, (5, 17, 30, 9, 2, 22)),
+}
+
+
+def _grid_engine(cfg, params, grid, policy="serial"):
+    C, B, L, _ = GRIDS[grid]
+    return ServeEngine(cfg, params, ServeConfig(
+        max_slots=B, max_len=L, prefill_chunk=C, policy=policy))
+
+
+def _slot_grid_engine(cfg, params, grid, monkeypatch, policy="serial"):
+    """The same engine with every prefill dispatch on the slot grid
+    (row i = slot i, no ``row_slot``)."""
+    C, B, _, _ = GRIDS[grid]
+    with monkeypatch.context() as m:
+        m.setattr(engine_mod, "PREFILL_ROW_TOKENS", B * C)
+        eng = _grid_engine(cfg, params, grid, policy)
+    assert eng._prefill_rows == B
+    return eng
+
+
+def _rows_computed(eng, grid):
+    C, B, _, _ = GRIDS[grid]
+    return engine_mod.prefill_rows(B, C) * C * eng.dispatch_counts["prefill"]
+
+
+@pytest.mark.parametrize("policy", ["serial", "interleaved"])
+@pytest.mark.parametrize("grid", sorted(GRIDS))
+def test_row_grid_matches_slot_grid(setup, monkeypatch, grid, policy):
+    """Waves of several requests, then of one as slots free, with prompts
+    over several chunks: the row grid emits the slot grid's greedy tokens,
+    and ``token_slots`` counts the rows each dispatch computed."""
+    cfg, params = setup
+    rng = np.random.default_rng(12)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in GRIDS[grid][3]]
+    engines = [_grid_engine(cfg, params, grid, policy),
+               _slot_grid_engine(cfg, params, grid, monkeypatch, policy)]
+    out = []
+    for eng in engines:
+        for p in prompts:
+            eng.add_request(p, max_new_tokens=4)
+        out.append(eng.run_until_done())
+    assert out[0] == out[1]
+    rows, slot_grid = engines
+    assert rows.prefill_stats["token_slots"] == _rows_computed(rows, grid)
+    assert rows.prefill_stats["valid_tokens"] \
+        == slot_grid.prefill_stats["valid_tokens"]
+    if rows._prefill_rows < rows.scfg.max_slots:
+        assert rows.prefill_stats["token_slots"] \
+            < slot_grid.prefill_stats["token_slots"]
+
+
+@pytest.mark.parametrize("grid", sorted(GRIDS))
+def test_row_grid_leaves_other_slots(setup, grid):
+    """A wave's prefill dispatches write no K/V row of a slot outside the
+    wave: a resident request's and a free slot's stay bit-identical."""
+    cfg, params = setup
+    _, B, _, lens = GRIDS[grid]
+    rng = np.random.default_rng(13)
+    eng = _grid_engine(cfg, params, grid)
+    eng.add_request(rng.integers(0, cfg.vocab_size, lens[0]))
+    eng.prefill_wave(eng.admit_wave(limit=1))
+    for n in lens[1:3]:
+        eng.add_request(rng.integers(0, cfg.vocab_size, n))
+    wave = eng.admit_wave(limit=2)
+    outside = [s for s in range(B) if s not in {s for s, _ in wave}]
+    before = {k: np.asarray(v)
+              for k, v in engine_mod._flatten_cache(eng.cache).items()}
+    job = eng.build_prefill_job(wave)
+    d0 = eng.dispatch_counts["prefill"]
+    t0 = eng.prefill_stats["token_slots"]
+    while not job.done:
+        eng.dispatch_prefill_chunk(job)
+    C = GRIDS[grid][0]
+    assert eng.prefill_stats["token_slots"] - t0 == \
+        engine_mod.prefill_rows(B, C) * C * (eng.dispatch_counts["prefill"]
+                                             - d0)
+    for k, v in engine_mod._flatten_cache(eng.cache).items():
+        np.testing.assert_array_equal(np.asarray(v)[:, outside],
+                                      before[k][:, outside], err_msg=k)
+
+
+@pytest.mark.parametrize("grid", sorted(GRIDS))
+def test_row_grid_restored_prefix(setup, monkeypatch, grid):
+    """A request whose prefix is restored from a snapshot (``prefill_start``
+    inside its second chunk) prefills only its suffix beside a fresh
+    request: the row grid emits the slot grid's tokens and a full
+    prefill's."""
+    cfg, params = setup
+    C, _, _, lens = GRIDS[grid]
+    rng = np.random.default_rng(14)
+    prompt = rng.integers(0, cfg.vocab_size, max(lens)).astype(np.int32)
+    other = rng.integers(0, cfg.vocab_size, lens[1]).astype(np.int32)
+    src = _grid_engine(cfg, params, grid)
+    src.add_request(prompt, gid=0)
+    src.prefill_wave(src.admit_wave())
+    snap = src.export_kv_snapshot()[0]
+    P = C + C // 2
+    assert P < len(prompt) - 1
+    restore = {"prefix_len": P, "snapshot_step": 0,
+               "cache": {k: v[:, :P] for k, v in snap["cache"].items()}}
+
+    def serve(eng, restored):
+        rid = eng.add_request(prompt, max_new_tokens=4, gid=0,
+                              restore=restore if restored else None)
+        eng.add_request(other, max_new_tokens=4)
+        out = eng.run_until_done()
+        return out, rid
+
+    rows = _grid_engine(cfg, params, grid)
+    got, rid = serve(rows, True)
+    want, _ = serve(_slot_grid_engine(cfg, params, grid, monkeypatch), True)
+    fresh, _ = serve(_grid_engine(cfg, params, grid), False)
+    assert got == want == fresh
+    assert rows.snapshot_stats["restored_tokens"] == P
+    assert rows.prefill_stats["valid_tokens"] == \
+        len(prompt) - 1 - P + len(other) - 1
+    assert rows.prefill_stats["token_slots"] == _rows_computed(rows, grid)

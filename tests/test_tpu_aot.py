@@ -139,6 +139,10 @@ def _serving_args(cfg, step):
         return fn, (params, cache, rows, rows, rows, rows,
                     _s((SLOTS, CHUNK), jnp.bool_), i32, i32) + decode
     fn = functools.partial(T.prefill_chunk, cfg, offset=CHUNK)
+    if step == "prefill_row_grid":
+        # one row that names its slot
+        return fn, (params, _s((1, CHUNK), jnp.int32), cache,
+                    _s((1, CHUNK), jnp.bool_), _s((1,), jnp.int32))
     return fn, (params, _s((SLOTS, CHUNK), jnp.int32), cache,
                 _s((SLOTS, CHUNK), jnp.bool_))
 
@@ -148,6 +152,8 @@ def _serving_args(cfg, step):
     ("fused_step_packed", False),
     ("prefill_chunk", False),
     ("prefill_chunk", True),
+    ("prefill_row_grid", False),
+    ("prefill_row_grid", True),
 ])
 def test_olmo_serving_step_fits_one_chip(compile_for_chip, step, use_pallas):
     """The served olmo-1b step programs at full width (8 slots x 2048):
@@ -177,6 +183,12 @@ def _engine_program(cfg, step):
         fn = engine._jit_prefill(cfg, CHUNK)
         args = (params, _s((SLOTS, CHUNK), jnp.int32), cache,
                 _s((SLOTS, CHUNK), jnp.bool_))
+    elif step == "prefill_row_grid":
+        # the same program over the one row of a 256-token chunk that
+        # names its slot: what the engine dispatches at this chunk
+        fn = engine._jit_prefill(cfg, CHUNK)
+        args = (params, _s((1, CHUNK), jnp.int32), cache,
+                _s((1, CHUNK), jnp.bool_), _s((1,), jnp.int32))
     else:
         fn, args, params = engine.reset_slots, (cache, i32), {}
     return fn, args, cache, params
@@ -216,7 +228,7 @@ def _whole_cache_writers(hlo: str, leaf_elems: int):
 
 
 @pytest.mark.parametrize("step", ["decode_and_sample", "prefill_chunk",
-                                  "reset_slots"])
+                                  "prefill_row_grid", "reset_slots"])
 def test_olmo_step_updates_cache_in_place(compile_for_chip, step):
     """The engine's olmo-1b decode, prefill and admission-reset programs
     at full width donate the KV cache and write into it in place: the
@@ -239,3 +251,16 @@ def test_olmo_step_updates_cache_in_place(compile_for_chip, step):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert abs(held - (weights_b + cache_b)) < 0.03 * (weights_b + cache_b), \
         (held / 2**30, (weights_b + cache_b) / 2**30)
+
+
+def test_olmo_row_grid_prefill_holds_less(compile_for_chip):
+    """The engine's olmo-1b prefill over one row naming its slot compiles
+    for one v5e beside the slot grid's, and holds less temporary memory."""
+    cfg = get_arch("olmo-1b")
+    assert engine.prefill_rows(SLOTS, CHUNK) == 1
+    temp = {}
+    for step in ("prefill_chunk", "prefill_row_grid"):
+        fn, args, _, _ = _engine_program(cfg, step)
+        temp[step] = compile_for_chip(fn, *args).memory_analysis() \
+            .temp_size_in_bytes
+    assert temp["prefill_row_grid"] < temp["prefill_chunk"], temp
