@@ -319,18 +319,17 @@ def _store_kv(cfg: ModelConfig, cache: dict, put, k_new: jax.Array,
 
 def _layer_kv(cfg: ModelConfig, cache: dict, layer,
               span: Optional[int] = None, slots: Optional[jax.Array] = None):
-    """Layer ``layer``'s K/V, position-major (B, span, KH, hd): positions
-    [0, span) (all when None) of every slot, or of the ``slots`` rows
-    gathered; an int8 cache is dequantized after the gather."""
+    """Layer ``layer``'s K/V, position-major (R, span, KH, hd): positions
+    [0, span) of the ``slots`` rows gathered (prefill), or every position
+    of every slot when both are absent (decode); an int8 cache is
+    dequantized after the read."""
     def read(name):
-        if slots is not None:
-            # one gather from the stacked leaf: only the named rows' span
-            # is read, never the layer's other slots
-            return cache[name][layer, slots, :span]
-        x = jax.lax.dynamic_index_in_dim(cache[name], layer, 0, keepdims=False)
-        if span is not None:
-            x = jax.lax.slice_in_dim(x, 0, span, axis=1)
-        return x
+        if slots is None:
+            return jax.lax.dynamic_index_in_dim(cache[name], layer, 0,
+                                                keepdims=False)
+        # one gather from the stacked leaf: only the named rows' span is
+        # read, never the layer's other slots
+        return cache[name][layer, slots, :span]
     k, v = read("k"), read("v")
     if cfg.kv_dtype == "int8":
         k, v = _dequantize(k, read("k_scale")), _dequantize(v, read("v_scale"))
@@ -351,16 +350,15 @@ def _pallas_flash(q, k, v, **kw):
 # --------------------------------------------------------------------------- #
 def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
                              cache: dict, layer, tok_valid: jax.Array,
-                             offset: int,
-                             row_slot: Optional[jax.Array] = None):
+                             offset: int, row_slot: jax.Array):
     """One prefill chunk against the slot cache. x: (R, C, d) at global
     positions [offset, offset+C); ``cache`` holds this attention position's
     stacked leaves and ``layer`` indexes them. Row j is cache slot
-    ``row_slot[j]`` ((R,) int32; None: row j is slot j, R = every slot).
-    Writes the chunk's K/V rows into the cache (``tok_valid`` (R, C) False
-    drops a write, so other slots' rows are untouched) and attends
-    causally over the rows' own cache[:offset+C] via the flash path — one
-    dispatch covers R requests' chunks instead of R*C decode steps.
+    ``row_slot[j]`` ((R,) int32). Writes the chunk's K/V rows into the
+    cache (``tok_valid`` (R, C) False drops a write, so other slots' rows
+    are untouched) and attends causally over the rows' own
+    cache[:offset+C] via the flash path — one dispatch covers R requests'
+    chunks instead of R*C decode steps.
 
     Returns (out (R, C, d), new_cache). Padding rows (tok_valid False)
     produce garbage outputs — callers discard them."""
@@ -369,7 +367,7 @@ def attention_prefill_cached(cfg: ModelConfig, p: dict, x: jax.Array,
     positions = offset + jnp.broadcast_to(jnp.arange(C)[None], (R, C))
     q, k_new, v_new = qkv_project(cfg, p, x, positions)
     pos = jnp.where(tok_valid, positions, S)
-    slot = (jnp.arange(R) if row_slot is None else row_slot)[:, None]
+    slot = row_slot[:, None]
     cache, _ = _store_kv(
         cfg, cache, lambda leaf, r: _write_rows(leaf, layer, slot, pos, r),
         k_new, v_new)

@@ -484,7 +484,7 @@ def decode_superstep(cfg: ModelConfig, params: dict, cache: dict,
 
 
 def fused_step(cfg: ModelConfig, params: dict, cache: dict,
-               tokens: jax.Array, tok_valid: jax.Array,
+               tokens: jax.Array, tok_valid: jax.Array, row_slot: jax.Array,
                last_tok: jax.Array, lens: jax.Array, active: jax.Array,
                gen_count: jax.Array, max_new: jax.Array, rng: jax.Array, *,
                offset: int, temperature: float, eos_token: Optional[int],
@@ -500,7 +500,7 @@ def fused_step(cfg: ModelConfig, params: dict, cache: dict,
     fetch, cache, last_tok, lens, gen_count, rng = decode_and_sample(
         cfg, params, cache, last_tok, lens, active, gen_count, max_new,
         rng, temperature=temperature, eos_token=eos_token, max_len=max_len)
-    cache = prefill_chunk(cfg, params, tokens, cache, tok_valid,
+    cache = prefill_chunk(cfg, params, tokens, cache, tok_valid, row_slot,
                           offset=offset)
     return fetch, cache, last_tok, lens, gen_count, rng
 
@@ -541,7 +541,8 @@ def supports_batched_prefill(cfg: ModelConfig) -> bool:
 
 def _apply_block_prefill(cfg: ModelConfig, kind: Tuple[str, str], p: dict,
                          x: jax.Array, cache: dict, layer,
-                         tok_valid: jax.Array, offset: int, row_slot):
+                         tok_valid: jax.Array, offset: int,
+                         row_slot: jax.Array):
     """Chunk-of-prompt block. x: (R, C, d). Returns (x, new_cache)."""
     mixer, ffn = kind
     if mixer != "attn":
@@ -568,13 +569,14 @@ def prefill_chunk(cfg: ModelConfig, params: dict, tokens: jax.Array,
     writes its chunk K/V into the cache (writes masked by ``tok_valid``,
     so only admitted slots' rows change). Row j is cache slot
     ``row_slot[j]`` ((R,) int32), so a dispatch carries only the rows being
-    prefilled; None is the slot grid, row j = slot j over every slot.
-    Returns the new cache.
+    prefilled. Returns the new cache.
 
     Prefill emits no logits: the engine's first generation step feeds the
     last prompt token, so the summarization stage is pure cache fill —
     prefilling an S-token prompt costs ceil(S/C) dispatches instead of S
     sequential decode steps."""
+    if row_slot is None:   # four arguments (one row per slot, tools/aot.py)
+        row_slot = jnp.arange(tokens.shape[0], dtype=jnp.int32)
     x = L.embed_tokens(params["embed"], tokens, cfg.d_model)
 
     def apply(kind, p, x, c, layer):

@@ -345,8 +345,8 @@ class MetricsHub:
         chunk, valid = int(ev["chunk"]), int(ev["valid"])
         self.counter("prefill_valid_tokens").inc(valid)
         # computed token slots per dispatch: a chunk dispatch computes the
-        # rows it carries (``rows``: the packed lanes used, the unpacked
-        # row grid, max_slots on the slot grid); a sequential (fallback)
+        # rows it carries (``rows``: the packed lanes used, or the unpacked
+        # rows that name their slots); a sequential (fallback)
         # event stands for `valid` one-token full-batch dispatches — the
         # same rules engine.prefill_stats uses
         max_slots = int(self.header["serve"]["max_slots"]) if self.header \

@@ -7,7 +7,7 @@ valid-token fraction the paper's Fig. 10 occupancy assumes is lost exactly
 on the workloads PIM serving targets (many short summarization prompts).
 
 ``plan_packed_job`` instead treats a dispatch as up to ``max_slots`` *lanes*
-of ``chunk`` columns — decoupled from the slot grid, since every token
+of ``chunk`` columns — decoupled from the cache's slots, since every token
 carries its true (slot, position) target — and first-fit-decreasing packs
 the wave's prompt segments into as few lanes as possible:
 

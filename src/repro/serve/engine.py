@@ -6,8 +6,7 @@ The engine is the TPU realization of the paper's two-phase inference flow:
     their slots' KV cache in O(ceil(S/chunk)) dispatches instead of S
     teacher-forced decode steps; a dispatch carries only the rows of the
     requests with tokens in its chunk, each row naming its slot, as many
-    as fill ``PREFILL_ROW_TOKENS`` (one 256-token row; every slot at short
-    chunks);
+    as fill ``PREFILL_ROW_TOKENS`` (one 256-token row);
   * generation (decode) — bandwidth-bound: one jit'd fused
     decode+sample+terminate dispatch across all active slots per emitted
     token; the only host sync is fetching the (token, done, len) triple —
@@ -147,8 +146,9 @@ def _jit_decode(cfg: ModelConfig):
 
 @functools.lru_cache(maxsize=None)
 def _jit_prefill(cfg: ModelConfig, offset: int):
-    """One jitted prefill per chunk offset. Called with four arguments it
-    runs the slot grid; with a fifth, ``row_slot``, the row grid."""
+    """One jitted prefill per chunk offset, over rows that name their slots
+    (``row_slot``, or slots 0..R-1 when left out); jax.jit specializes per
+    row count inside each entry."""
     return jax.jit(functools.partial(T.prefill_chunk, cfg, offset=offset),
                    donate_argnums=2)
 
@@ -240,10 +240,6 @@ class ServeConfig:
     policy: str = "serial"
     sub_batch: int = 0
     map_dims: Optional[Tuple[int, int]] = None
-    # double-buffered token fetch: start the decode result's device->host
-    # copy asynchronously at dispatch so the step's co-scheduled prefill
-    # chunk (and host bookkeeping) overlaps the transfer.
-    double_buffer: bool = True
     # packed prefill: first-fit-decreasing pack several short prompts (or a
     # long prompt's tail plus short prompts) into each chunk row, so the
     # per-dispatch valid-token fraction stays near 1 on mixed workloads
@@ -715,6 +711,27 @@ class ServeEngine:
         by every later admission batch (and engine instance)."""
         return _jit_prefill(self.cfg, chunk_idx * self.scfg.prefill_chunk)
 
+    @staticmethod
+    def _chunk_rows(job: PrefillJob, c: int, R: int):
+        """Chunk ``c``'s rows in groups of ``R``: the slots with tokens in
+        the chunk, each row naming its slot, the last group padded with
+        its first row and the padding marked invalid (its writes drop).
+        A group of one row per slot is laid out row i = slot i.
+        Yields (slots, row_slot, tokens, valid) per group."""
+        window = slice(c * job.chunk, (c + 1) * job.chunk)
+        vc = job.valid[:, window]
+        slots = [int(s) for s, _ in job.wave if vc[s].any()]
+        if R == len(vc) and slots:
+            yield slots, np.arange(R, dtype=np.int32), job.tokens[:, window], vc
+            return
+        for g in range(0, len(slots), R):
+            group = slots[g:g + R]
+            row_slot = np.full(R, group[0], np.int32)
+            row_slot[:len(group)] = group
+            valid = vc[row_slot]
+            valid[len(group):] = False
+            yield group, row_slot, job.tokens[row_slot, window], valid
+
     def _account_chunk_prefill(self, job: PrefillJob, c: int,
                                vc: np.ndarray, slots: List[int], *,
                                overlap: bool, fused: bool) -> None:
@@ -753,42 +770,25 @@ class ServeEngine:
 
     def dispatch_prefill_chunk(self, job: PrefillJob, *,
                                overlap: bool = False) -> None:
-        """Run the job's next chunk through the batched flash prefill path.
-        The chunk's rows with prompt tokens go out in dispatches of
-        ``prefill_rows`` rows, each row naming its slot, the last padded
-        with rows whose writes drop; at one row per slot the dispatch is
-        the slot grid. ``overlap=True`` marks the dispatches as
+        """Run the job's next chunk through the batched flash prefill path,
+        in dispatches of ``prefill_rows`` rows (``_chunk_rows``). At one row
+        per slot the call leaves out ``row_slot`` (row i is slot i, its
+        default): ``benchmarks/onchip/tests/test_faults.py`` plants a
+        four-argument prefill. ``overlap=True`` marks the dispatches as
         co-scheduled with this step's decode (recorded in the trace; the
         replay merges the two streams)."""
         if isinstance(job, PackedPrefillJob):
             return self._dispatch_packed_chunk(job, overlap=overlap)
-        c, C = job.next_chunk, job.chunk
+        c = job.next_chunk
         job.next_chunk += 1
-        window = slice(c * C, (c + 1) * C)
-        vc = job.valid[:, window]
-        slots = [int(s) for s, _ in job.wave if vc[s].any()]
-        if not slots:
-            return
-        R = self._prefill_rows
         fn = self._get_prefill_fn(c)
-        for g in range(0, len(slots), R):
-            group = slots[g:g + R]
+        R = self._prefill_rows
+        for group, row_slot, tokens, valid in self._chunk_rows(job, c, R):
             with span(self.spans, "serve.prefill"):
-                if R == self.scfg.max_slots:   # the slot grid: row i, slot i
-                    valid = vc
-                    self.cache = fn(self.params,
-                                    jnp.asarray(job.tokens[:, window]),
-                                    self.cache, jnp.asarray(valid))
-                else:
-                    row_slot = np.full(R, group[0], np.int32)
-                    row_slot[:len(group)] = group
-                    valid = vc[row_slot]
-                    valid[len(group):] = False
-                    self.cache = fn(
-                        self.params,
-                        jnp.asarray(job.tokens[row_slot, window]),
-                        self.cache, jnp.asarray(valid),
-                        jnp.asarray(row_slot))
+                named = () if R == self.scfg.max_slots \
+                    else (jnp.asarray(row_slot),)
+                self.cache = fn(self.params, jnp.asarray(tokens), self.cache,
+                                jnp.asarray(valid), *named)
                 self.dispatch_counts["prefill"] += 1
                 self._account_chunk_prefill(job, c, valid, group,
                                             overlap=overlap, fused=False)
@@ -898,7 +898,7 @@ class ServeEngine:
     def _start_fetch(self, fetch) -> None:
         """Double-buffered fetch: start the result's device->host copy at
         dispatch so co-scheduled work overlaps the transfer."""
-        if self.scfg.double_buffer and hasattr(fetch, "copy_to_host_async"):
+        if hasattr(fetch, "copy_to_host_async"):
             fetch.copy_to_host_async()
             self.async_fetches += 1
 
@@ -955,19 +955,18 @@ class ServeEngine:
             else:
                 c = job.next_chunk
                 job.next_chunk += 1
-                vc = job.valid[:, c * C:(c + 1) * C]
-                assert vc.any(), "fused step dispatched an empty prefill chunk"
+                # one group of max_slots rows: one fused dispatch per chunk
+                (group, row_slot, tokens, valid), = self._chunk_rows(
+                    job, c, self.scfg.max_slots)
                 fn = _jit_fused_step(
                     self.cfg, self.scfg.temperature, self.scfg.eos_token,
                     self.scfg.max_len, c * C)
                 (fetch, self.cache, self.last_tok, self.lens, self.gen_count,
                  self._rng) = fn(
-                    self.params, self.cache,
-                    jnp.asarray(job.tokens[:, c * C:(c + 1) * C]),
-                    jnp.asarray(vc), *common)
-                self._account_chunk_prefill(
-                    job, c, vc, [int(s) for s, _ in job.wave if vc[s].any()],
-                    overlap=True, fused=True)
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(valid), jnp.asarray(row_slot), *common)
+                self._account_chunk_prefill(job, c, valid, group,
+                                            overlap=True, fused=True)
             self.dispatch_counts["fused"] += 1
             self._start_fetch(fetch)
             return PendingDecode(fetch=fetch, active_np=active_np, n_tok=n_tok,

@@ -253,10 +253,12 @@ def test_pallas_prefill_refuses_ragged_tail(setup):
         jax.eval_shape(step, params, tokens, cache, valid)
 
 
-def test_prefill_chunk_row_slot_matches_slot_grid(setup):
+def test_prefill_chunk_row_slot_groupings_agree(setup):
     """Unit-level: ``T.prefill_chunk`` over rows that name their slots, one
-    row per dispatch or several with a padding row, writes the same K/V as
-    the slot-grid call, and leaves the other slots' rows as they were."""
+    row per dispatch or several with a padding row, or over every slot in
+    slot order with ``row_slot`` left out, writes the same K/V as the one
+    dispatch carrying both rows, and leaves the other slots' rows as they
+    were."""
     import jax.numpy as jnp
     cfg, params = setup
     B, L, C = 4, 64, 8
@@ -275,41 +277,40 @@ def test_prefill_chunk_row_slot_matches_slot_grid(setup):
     step = {c: jax.jit(functools.partial(T.prefill_chunk, cfg, offset=c * C))
             for c in range(2)}
 
-    def run(groups):
+    def run(groups, named=True):
         cache = cache0
         for c in range(2):
             w = slice(c * C, (c + 1) * C)
             for rows in groups:
-                if rows is None:
-                    cache = step[c](params, toks[:, w], cache, valid[:, w])
-                    continue
                 v = valid[rows, w]
                 v[len(set(rows)):] = False       # a repeated row pads
-                cache = step[c](params, toks[rows, w], cache, v,
-                                jnp.asarray(rows, jnp.int32))
+                row_slot = (jnp.asarray(rows, jnp.int32),) if named else ()
+                cache = step[c](params, toks[rows, w], cache, v, *row_slot)
         return cache
 
-    want = run([None])
-    for groups in ([[2], [0]], [[0, 2]], [[2, 0, 2]]):
-        got = run(groups)
+    want = run([[0, 2]])
+    for groups, named in (([[2], [0]], True), ([[2, 0, 2]], True),
+                          ([list(range(B))], False)):
+        got = run(groups, named)
         for pos in want:
             for k in want[pos]:
                 a = np.asarray(want[pos][k], np.float32)
                 b = np.asarray(got[pos][k], np.float32)
                 np.testing.assert_array_equal(a, b,
                                               err_msg=f"{groups} {pos}/{k}")
-                np.testing.assert_array_equal(
-                    b[:, [1, 3]],
-                    np.asarray(cache0[pos][k], np.float32)[:, [1, 3]])
+                for out in (a, b):
+                    np.testing.assert_array_equal(
+                        out[:, [1, 3]],
+                        np.asarray(cache0[pos][k], np.float32)[:, [1, 3]])
 
 
 # (prefill_chunk, max_slots, max_len, prompt lengths): one row per dispatch
-# at 256-token chunks, two rows (the last group padded) at 128, the slot
-# grid at 8; every set spans several chunks and mixes lengths
+# at 256-token chunks, two rows (the last group padded) at 128, a row for
+# every slot at 8; every set spans several chunks and mixes lengths
 GRIDS = {
     "rows1": (256, 4, 512, (300, 40, 470, 260, 3, 130)),
     "rows2": (128, 4, 512, (300, 40, 470, 260, 3, 130)),
-    "slot_grid": (8, 4, 64, (5, 17, 30, 9, 2, 22)),
+    "rows4": (8, 4, 64, (5, 17, 30, 9, 2, 22)),
 }
 
 
@@ -319,15 +320,36 @@ def _grid_engine(cfg, params, grid, policy="serial"):
         max_slots=B, max_len=L, prefill_chunk=C, policy=policy))
 
 
-def _slot_grid_engine(cfg, params, grid, monkeypatch, policy="serial"):
-    """The same engine with every prefill dispatch on the slot grid
-    (row i = slot i, no ``row_slot``)."""
-    C, B, _, _ = GRIDS[grid]
-    with monkeypatch.context() as m:
-        m.setattr(engine_mod, "PREFILL_ROW_TOKENS", B * C)
-        eng = _grid_engine(cfg, params, grid, policy)
-    assert eng._prefill_rows == B
-    return eng
+def _sequential_engine(cfg, params, grid):
+    """The teacher-forced reference at the grid's slots and length."""
+    C, B, L, _ = GRIDS[grid]
+    return ServeEngine(cfg, params, ServeConfig(
+        max_slots=B, max_len=L, prefill_chunk=C, prefill_mode="sequential"))
+
+
+def _serve_grid(eng, grid, cfg):
+    rng = np.random.default_rng(12)
+    for n in GRIDS[grid][3]:
+        eng.add_request(rng.integers(0, cfg.vocab_size, n).astype(np.int32),
+                        max_new_tokens=4)
+    return eng.run_until_done()
+
+
+@pytest.fixture(scope="module")
+def sequential_tokens(setup):
+    """``sequential_tokens(grid)``: the sequential reference's greedy tokens
+    for the grid's prompts, served once and shared by every policy (and by
+    grids that differ only in the chunk, which the reference ignores)."""
+    cfg, params = setup
+    memo = {}
+
+    def tokens(grid):
+        key = GRIDS[grid][1:]
+        if key not in memo:
+            memo[key] = _serve_grid(_sequential_engine(cfg, params, grid),
+                                    grid, cfg)
+        return memo[key]
+    return tokens
 
 
 def _rows_computed(eng, grid):
@@ -337,29 +359,21 @@ def _rows_computed(eng, grid):
 
 @pytest.mark.parametrize("policy", ["serial", "interleaved"])
 @pytest.mark.parametrize("grid", sorted(GRIDS))
-def test_row_grid_matches_slot_grid(setup, monkeypatch, grid, policy):
+def test_row_grid_matches_sequential(setup, sequential_tokens, grid, policy):
     """Waves of several requests, then of one as slots free, with prompts
-    over several chunks: the row grid emits the slot grid's greedy tokens,
-    and ``token_slots`` counts the rows each dispatch computed."""
+    over several chunks: the row grid emits the sequential reference's
+    greedy tokens, and ``token_slots`` counts the rows each dispatch
+    computed."""
     cfg, params = setup
-    rng = np.random.default_rng(12)
-    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
-               for n in GRIDS[grid][3]]
-    engines = [_grid_engine(cfg, params, grid, policy),
-               _slot_grid_engine(cfg, params, grid, monkeypatch, policy)]
-    out = []
-    for eng in engines:
-        for p in prompts:
-            eng.add_request(p, max_new_tokens=4)
-        out.append(eng.run_until_done())
-    assert out[0] == out[1]
-    rows, slot_grid = engines
-    assert rows.prefill_stats["token_slots"] == _rows_computed(rows, grid)
-    assert rows.prefill_stats["valid_tokens"] \
-        == slot_grid.prefill_stats["valid_tokens"]
-    if rows._prefill_rows < rows.scfg.max_slots:
-        assert rows.prefill_stats["token_slots"] \
-            < slot_grid.prefill_stats["token_slots"]
+    C, B, _, lens = GRIDS[grid]
+    eng = _grid_engine(cfg, params, grid, policy)
+    assert _serve_grid(eng, grid, cfg) == sequential_tokens(grid)
+    stats = eng.prefill_stats
+    assert stats["token_slots"] == _rows_computed(eng, grid)
+    assert stats["valid_tokens"] == sum(lens) - len(lens)
+    if eng._prefill_rows < B:
+        assert stats["token_slots"] \
+            < B * C * eng.dispatch_counts["prefill"]
 
 
 @pytest.mark.parametrize("grid", sorted(GRIDS))
@@ -393,11 +407,11 @@ def test_row_grid_leaves_other_slots(setup, grid):
 
 
 @pytest.mark.parametrize("grid", sorted(GRIDS))
-def test_row_grid_restored_prefix(setup, monkeypatch, grid):
+def test_row_grid_restored_prefix(setup, grid):
     """A request whose prefix is restored from a snapshot (``prefill_start``
     inside its second chunk) prefills only its suffix beside a fresh
-    request: the row grid emits the slot grid's tokens and a full
-    prefill's."""
+    request: the row grid emits a full prefill's tokens and the sequential
+    reference's."""
     cfg, params = setup
     C, _, _, lens = GRIDS[grid]
     rng = np.random.default_rng(14)
@@ -421,9 +435,9 @@ def test_row_grid_restored_prefix(setup, monkeypatch, grid):
 
     rows = _grid_engine(cfg, params, grid)
     got, rid = serve(rows, True)
-    want, _ = serve(_slot_grid_engine(cfg, params, grid, monkeypatch), True)
     fresh, _ = serve(_grid_engine(cfg, params, grid), False)
-    assert got == want == fresh
+    want, _ = serve(_sequential_engine(cfg, params, grid), False)
+    assert got == fresh == want
     assert rows.snapshot_stats["restored_tokens"] == P
     assert rows.prefill_stats["valid_tokens"] == \
         len(prompt) - 1 - P + len(other) - 1

@@ -180,9 +180,11 @@ def _engine_program(cfg, step):
         args = (params, cache, i32, i32, _s((SLOTS,), jnp.bool_), i32, i32,
                 _s((2,), jnp.uint32))
     elif step == "prefill_chunk":
+        # a row for every slot, each naming its slot: the fused step's
+        # prefill, and the engine's at chunks of 256 / SLOTS tokens
         fn = engine._jit_prefill(cfg, CHUNK)
         args = (params, _s((SLOTS, CHUNK), jnp.int32), cache,
-                _s((SLOTS, CHUNK), jnp.bool_))
+                _s((SLOTS, CHUNK), jnp.bool_), i32)
     elif step == "prefill_row_grid":
         # the same program over the one row of a 256-token chunk that
         # names its slot: what the engine dispatches at this chunk
@@ -254,13 +256,37 @@ def test_olmo_step_updates_cache_in_place(compile_for_chip, step):
 
 
 def test_olmo_row_grid_prefill_holds_less(compile_for_chip):
-    """The engine's olmo-1b prefill over one row naming its slot compiles
-    for one v5e beside the slot grid's, and holds less temporary memory."""
+    """The engine's olmo-1b prefill over one row naming its slot, and over
+    a row for every slot, compiles for one v5e with temporaries under one
+    slot's K+V of one layer: the attended span is gathered for the rows,
+    never copied out of the whole layer."""
     cfg = get_arch("olmo-1b")
     assert engine.prefill_rows(SLOTS, CHUNK) == 1
     temp = {}
     for step in ("prefill_chunk", "prefill_row_grid"):
-        fn, args, _, _ = _engine_program(cfg, step)
+        fn, args, cache, _ = _engine_program(cfg, step)
         temp[step] = compile_for_chip(fn, *args).memory_analysis() \
             .temp_size_in_bytes
-    assert temp["prefill_row_grid"] < temp["prefill_chunk"], temp
+    leaf = cache["pos0"]["k"]
+    slot_layer_kv = 2 * math.prod(leaf.shape[2:]) * leaf.dtype.itemsize
+    assert max(temp.values()) < slot_layer_kv, (temp, slot_layer_kv)
+
+
+def test_olmo_max_slots_prefill_at_the_last_offset(compile_for_chip):
+    """The engine's olmo-1b prefill over a row for every slot at the
+    benchmark cells' shape (16 slots x 2048) and the last chunk offset:
+    what a fused step, or a chunk of 16 tokens or fewer, dispatches. The
+    gathered span of 16 rows costs temporaries that grow with the offset,
+    but they stay under one KV cache (no second cache is ever held) and
+    the program fits one v5e."""
+    cfg, slots = get_arch("olmo-1b"), 16
+    params = abstract_params(T.param_defs(cfg))
+    cache = abstract_params(T.cache_defs(cfg, slots, MAX_LEN))
+    fn = engine._jit_prefill(cfg, MAX_LEN - CHUNK)
+    mem = compile_for_chip(fn, params, _s((slots, CHUNK), jnp.int32), cache,
+                           _s((slots, CHUNK), jnp.bool_),
+                           _s((slots,), jnp.int32)).memory_analysis()
+    assert mem.temp_size_in_bytes < _nbytes(cache), mem.temp_size_in_bytes
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < V5E_HBM_BYTES, held / 2**30
